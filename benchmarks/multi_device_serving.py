@@ -8,14 +8,17 @@ only the queries it owns into an ``(S/N, qcap)`` lane matrix, traverses its
 own pool slice, and the ``(B,)`` result planes ``psum`` together.  The
 single-device engine traverses an always-safe ``(S, Q)`` lane matrix.
 
-Because jax pins its device topology at import, every engine variant runs
-in a fresh subprocess with ``--xla_force_host_platform_device_count`` set;
-the parent collates the children's JSON rows.  On this container (one CPU
-core) the devices are time-sliced, so the speedup that survives is the WORK
-reduction of tight per-device lane packing — total traversal lanes drop
-from ``S * Q`` to ``S * qcap`` with ``qcap`` the host-routed per-shard
-occupancy bound — plus the per-device parallelism headroom the lane counts
-document for real multi-chip hosts.
+On a TPU host every variant runs in this one process over a prefix of
+``jax.devices()`` (a chip belongs to one process; a child could not reach
+it), and device counts beyond the host's are skipped.  On the CPU, because
+jax pins its device topology at import, every engine variant runs in a
+fresh subprocess with ``--xla_force_host_platform_device_count`` set; the
+parent collates the children's JSON rows.  Forced host devices time-slice
+the CPU, so the speedup that survives there is the WORK reduction of tight
+per-device lane packing — total traversal lanes drop from ``S * Q`` to
+``S * qcap`` with ``qcap`` the host-routed per-shard occupancy bound — plus
+the per-device parallelism headroom the lane counts document for real
+multi-chip hosts.
 
 Acceptance gate: mesh at 4 devices >= 2x the single-device engine's read
 throughput (uniform batched gets, identical dataset/geometry/batch).
@@ -43,10 +46,9 @@ REPEATS = 3   # best-of-N: single-core container timing is noisy
 _REPO = pathlib.Path(__file__).resolve().parents[1]
 
 
-# --------------------------------------------------------------------- child
-def _child(mode: str, devices: int, n: int) -> None:
-    """One engine variant in an isolated forced-device process; prints one
-    JSON row on the last line of stdout."""
+# ------------------------------------------------------------------- variant
+def _variant(mode: str, devices: int, n: int) -> dict:
+    """One engine variant over the first ``devices`` devices -> its row."""
     import jax
 
     from repro.core import AulidConfig, partition_bulkload
@@ -96,7 +98,7 @@ def _child(mode: str, devices: int, n: int) -> None:
         "read_throughput_ops_s": round(best, 1),
         "mesh_devices": eng.stats()["mesh_devices"],
     }
-    print("ROW " + json.dumps(row))
+    return row
 
 
 # -------------------------------------------------------------------- parent
@@ -122,11 +124,16 @@ def _spawn(mode: str, devices: int, n: int) -> dict:
 
 
 def run(scale: str = "small") -> list[dict]:
+    import jax
     from .common import SCALE_N, print_table, save_results
     n = SCALE_N[scale]
-    rows = [_spawn("single", 1, n)]
-    for d in DEVICE_COUNTS:
-        rows.append(_spawn("mesh", d, n))
+    on_tpu = jax.default_backend() == "tpu"
+    counts = [d for d in DEVICE_COUNTS
+              if not on_tpu or d <= len(jax.devices())]
+    variant = _variant if on_tpu else _spawn
+    rows = [variant("single", 1, n)]
+    for d in counts:
+        rows.append(variant("mesh", d, n))
     base = rows[0]["read_throughput_ops_s"]
     for r in rows:
         r["speedup_vs_single_device"] = round(
@@ -136,18 +143,24 @@ def run(scale: str = "small") -> list[dict]:
                   "batch_q": BATCH_Q, "steps": STEPS, "repeats": REPEATS,
                   "gate_speedup": GATE_SPEEDUP,
                   "gate_devices": GATE_DEVICES,
-                  "note": ("forced host devices time-slice one CPU core: "
+                  "platform": jax.devices()[0].platform,
+                  "device_kind": jax.devices()[0].device_kind,
+                  "note": ("one process over the host's chips" if on_tpu
+                           else "forced host devices time-slice the CPU: "
                            "the measured speedup is the lane-packing work "
                            "reduction; lanes_per_device documents the "
                            "per-chip parallel headroom")})
     print_table(
         "Mesh-placed sharded serving: batched read throughput vs the "
-        "single-device engine (forced host devices)",
+        f"single-device engine ({jax.devices()[0].device_kind})",
         rows, ["engine", "devices", "shard_slots", "per_shard_qcap",
                "lanes_per_device", "read_throughput_ops_s",
                "speedup_vs_single_device"])
-    gate = next(r for r in rows
-                if r["engine"] == f"mesh_{GATE_DEVICES}dev")
+    gate = next((r for r in rows
+                 if r["engine"] == f"mesh_{GATE_DEVICES}dev"), None)
+    if gate is None:
+        print(f"\nno {GATE_DEVICES}-device mesh on this host: gate not run")
+        return rows
     sp = gate["speedup_vs_single_device"]
     print(f"\nmesh@{GATE_DEVICES} read-throughput speedup {sp:.2f}x "
           f"(acceptance gate: >= {GATE_SPEEDUP}x)")
@@ -158,6 +171,7 @@ def run(scale: str = "small") -> list[dict]:
 
 if __name__ == "__main__":
     if len(sys.argv) > 1 and sys.argv[1] == "--child":
-        _child(sys.argv[2], int(sys.argv[3]), int(sys.argv[4]))
+        print("ROW " + json.dumps(
+            _variant(sys.argv[2], int(sys.argv[3]), int(sys.argv[4]))))
     else:
         run(sys.argv[1] if len(sys.argv) > 1 else "small")

@@ -248,6 +248,8 @@ def main():
     ap.add_argument("--scale", default="small",
                     choices=["small", "paper", "large"])
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+    print(f"compile cache: {enable_compile_cache(REPO_ROOT)}", flush=True)
     mods = [args.module] if args.module else MODULES
     failures = []
     for name in mods:

@@ -448,9 +448,16 @@ def stack_device_indexes(dis: list[DeviceIndex], bounds: np.ndarray,
     shapes = {f: tuple(dim_cap(f, d)
                        for d in range(getattr(dis[0], f).ndim))
               for f, _ in _STACK_2D + _STACK_3D}
-    stacked = {f: np.stack([_pad_to(getattr(di, f), shapes[f], fill)
-                            for di in dis])
-               for f, fill in _STACK_2D + _STACK_3D}
+    # filled in place: a list of padded per-shard copies would double the
+    # host peak of a deployment-sized stack
+    stacked = {}
+    for f, fill in _STACK_2D + _STACK_3D:
+        out = np.full((len(dis),) + shapes[f], fill,
+                      dtype=getattr(dis[0], f).dtype)
+        for s, di in enumerate(dis):
+            a = getattr(di, f)
+            out[(s,) + tuple(slice(0, d) for d in a.shape)] = a
+        stacked[f] = out
     Lmax = shapes["leaf_keys"][0]
     return StackedDeviceIndex(
         bounds=np.asarray(bounds, dtype=np.uint64), dis=list(dis), **stacked,

@@ -23,7 +23,6 @@ import numpy as np
 jax.config.update("jax_enable_x64", True)
 
 import jax.numpy as jnp  # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
 from jax.sharding import PartitionSpec  # noqa: E402
 
 from .delta_overlay import (DeltaOverlay, UINT64_MAX, merge_overlays,  # noqa: E402
@@ -346,15 +345,14 @@ def overlay_merge_backend_fn(backend: str = "auto"):
     """The overlay-merge entry for a read backend, callable as
     ``fn(pack, batch_pack, cap_out) -> new_pack`` — the engines' write-path
     twin of ``lookup_backend_fns``: "jnp" merges with the reference above,
-    "fused"/"fused_interpret" route through the Pallas overlay-merge kernel
-    (interpret mode off-TPU, same degradation rule as the read path)."""
+    "fused" through the compiled Pallas overlay-merge kernel and
+    "fused_interpret" through the same kernel in interpret mode."""
     b = resolve_read_backend(backend)
     if b == "jnp":
         return merge_overlay_pack_jnp
     from ..kernels.overlay_merge.ops import overlay_merge_pack
-    interpret = (b == "fused_interpret"
-                 or jax.default_backend() != "tpu")
-    return functools.partial(overlay_merge_pack, interpret=interpret)
+    return functools.partial(overlay_merge_pack,
+                             interpret=(b == "fused_interpret"))
 
 
 def update_leaf_rows(arrs: dict, di: DeviceIndex) -> dict:
@@ -457,13 +455,21 @@ def scan_batch_overlay(arrs: dict, ovr: dict, q: jnp.ndarray, count: int = 100,
         max_blocks = max_blocks + hide // max(leaf_cap // 2, 1) + 1
     ks, ps, vs = scan_batch(arrs, q, count=base, height=height,
                             max_blocks=max_blocks)
-    return _overlay_scan_merge(ks, ps, vs, keys, pays, tombs, q, count)
+    return _overlay_scan_merge(ks, ps, vs, keys, pays, tombs, q, count, hide)
 
 
-def _overlay_scan_merge(ks, ps, vs, keys, pays, tombs, q, count: int):
+def _overlay_scan_merge(ks, ps, vs, keys, pays, tombs, q, count: int,
+                        live_bound: int):
     """Merge snapshot scan candidates with the overlay range (shared by the
     monolithic and sharded scans): snapshot keys the overlay owns lose, live
-    overlay entries in range union in, and the result re-sorts."""
+    overlay entries in range union in, and the result re-sorts.
+
+    The pack is sorted with its live entries first, and ``live_bound``
+    (static) bounds their number, so only that prefix is merged: a pack sized
+    for a large compaction threshold (gamma·n entries) would otherwise be
+    broadcast and sorted whole for every query."""
+    keys, pays, tombs = (keys[:live_bound], pays[:live_bound],
+                         tombs[:live_bound])
     cap = keys.shape[0]
     pos = jnp.searchsorted(keys, ks, side="left").astype(jnp.int32)
     owned = (pos < cap) & (jnp.take(keys, jnp.clip(pos, 0, cap - 1)) == ks)
@@ -494,23 +500,32 @@ def _overlay_scan_merge(ks, ps, vs, keys, pays, tombs, q, count: int):
 # (S*L,) leaf pools through the precomputed shard-successor chain, so a range
 # crossing a shard boundary keeps streaming blocks with no host round-trip.
 
-def stacked_device_arrays(sdi, bounds_version: int = 0
-                          ) -> dict[str, jnp.ndarray]:
-    """Move a :class:`StackedDeviceIndex`'s pools to device arrays.
+def stacked_host_arrays(sdi, bounds_version: int = 0) -> dict:
+    """The operand dict of a :class:`StackedDeviceIndex` with its pools
+    still host numpy arrays — what a placement (``jnp.asarray`` for one
+    device, ``place_stacked`` for a mesh) moves to the device.
 
     ``bounds_version`` records which boundary-table version the pack's
     ``bounds`` array belongs to (DESIGN.md §12) — informational for
     stats/tests; operand-pack caches are invalidated by the fresh
     ``snap_token`` every build stamps, so a split/merge (which always builds
     a new pack) can never serve reads through stale cached route operands."""
-    d = {f: jnp.asarray(getattr(sdi, f)) for f in _DEVICE_FIELDS}
-    d["meta"] = jnp.asarray(sdi.meta)
-    d["last_leaf_min"] = jnp.asarray(sdi.last_leaf_min)
-    d["bounds"] = jnp.asarray(sdi.bounds)
-    d["leaf_next_chain"] = jnp.asarray(sdi.leaf_next_chain)
+    d = {f: getattr(sdi, f) for f in _DEVICE_FIELDS}
+    d["meta"] = sdi.meta
+    d["last_leaf_min"] = sdi.last_leaf_min
+    d["bounds"] = sdi.bounds
+    d["leaf_next_chain"] = sdi.leaf_next_chain
     d["snap_token"] = new_snap_token()
     d["bounds_version"] = int(bounds_version)
     return d
+
+
+def stacked_device_arrays(sdi, bounds_version: int = 0
+                          ) -> dict[str, jnp.ndarray]:
+    """Move a :class:`StackedDeviceIndex`'s pools to the default device
+    (:func:`stacked_host_arrays` placed with ``jnp.asarray``)."""
+    return {k: jnp.asarray(v) if isinstance(v, np.ndarray) else v
+            for k, v in stacked_host_arrays(sdi, bounds_version).items()}
 
 
 @functools.partial(jax.jit, donate_argnames=("pools",))
@@ -652,32 +667,36 @@ def lookup_batch_sharded_overlay(stk: dict, ovr: dict, q: jnp.ndarray,
 # --------------------------------------------------------------------- backend
 # Read-backend dispatch (DESIGN.md §10): the serving engines bind their point-
 # lookup entry through here, so the fused Pallas kernel and the jnp gather
-# path are interchangeable behind one switch.  The jnp path stays the
-# correctness oracle; "auto" resolves to it on CPU (the automatic fallback)
-# and to the compiled fused kernel on a Pallas-capable backend.  Scans always
-# run the jnp path — the fused kernel covers point lookups.
+# path are interchangeable behind one switch.  "auto" is the jnp path on every
+# platform: it is the path that runs at deployment size, and the correctness
+# oracle.  "fused" is the compiled kernel and needs a TPU — it never degrades
+# to interpret mode; "fused_interpret" asks for interpret mode explicitly.
+# Scans always run the jnp path — the fused kernel covers point lookups.
 
 READ_BACKENDS = ("auto", "jnp", "fused", "fused_interpret")
 
 
 def resolve_read_backend(backend: str = "auto") -> str:
-    """Resolve "auto" against the jax backend: the fused kernel needs a real
-    Pallas lowering (TPU); everywhere else the jnp path serves reads."""
+    """Resolve a read-backend name: "auto" is "jnp"; "fused" raises unless
+    the default JAX backend is a TPU (the kernel's only compiled target)."""
     if backend not in READ_BACKENDS:
         raise ValueError(f"backend must be one of {READ_BACKENDS}, "
                          f"got {backend!r}")
     if backend == "auto":
-        return "fused" if jax.default_backend() == "tpu" else "jnp"
+        return "jnp"
+    if backend == "fused" and jax.default_backend() != "tpu":
+        raise ValueError(
+            f"backend 'fused' compiles the Pallas kernels for a TPU, but "
+            f"the default JAX backend is {jax.default_backend()!r}; use "
+            f"'fused_interpret' for interpret mode")
     return backend
 
 
 def lookup_backend_fns(backend: str = "auto", *, sharded: bool = False):
     """The overlay-merged point-lookup entry for a read backend, callable as
     ``fn(snap, ovr, q, height=...)`` — the engines' ``self._lookup`` shape.
-
-    "fused" on a non-TPU backend silently degrades to interpret mode (still
-    the fused kernel, still exact — just not compiled); "fused_interpret"
-    forces interpret mode everywhere (what tier-1 CI exercises)."""
+    "fused_interpret" runs the fused kernel in interpret mode (what tier-1
+    CI exercises)."""
     b = resolve_read_backend(backend)
     if b == "jnp":
         return lookup_batch_sharded_overlay if sharded \
@@ -686,9 +705,7 @@ def lookup_backend_fns(backend: str = "auto", *, sharded: bool = False):
         fused_lookup_batch_overlay, fused_lookup_batch_sharded_overlay)
     fn = fused_lookup_batch_sharded_overlay if sharded \
         else fused_lookup_batch_overlay
-    interpret = (b == "fused_interpret"
-                 or jax.default_backend() != "tpu")
-    return functools.partial(fn, interpret=interpret)
+    return functools.partial(fn, interpret=(b == "fused_interpret"))
 
 
 @functools.partial(jax.jit,
@@ -712,7 +729,7 @@ def scan_batch_sharded_overlay(stk: dict, ovr: dict, q: jnp.ndarray,
         max_blocks = max_blocks + hide // max(leaf_cap // 2, 1) + 1
     ks, ps, vs = scan_batch_sharded(stk, q, count=base, height=height,
                                     max_blocks=max_blocks, qcap=qcap)
-    return _overlay_scan_merge(ks, ps, vs, keys, pays, tombs, q, count)
+    return _overlay_scan_merge(ks, ps, vs, keys, pays, tombs, q, count, hide)
 
 
 # ------------------------------------------------------------------------ mesh
@@ -751,6 +768,18 @@ def mesh_local_shards(S: int, mesh) -> int:
             f"stacked shard slots S={S} not divisible by the index mesh's "
             f"{D} devices — pad shard slots to a device multiple")
     return S // D
+
+
+def psum_disjoint(x, axis: str = MESH_AXIS):
+    """``psum`` of contributions that are zero on all but one device.  TPU
+    all-reduces have no 64-bit form, so a u64 operand reduces as its two
+    32-bit halves (exact: one nonzero term per element, no carries)."""
+    if x.dtype != jnp.uint64:
+        return jax.lax.psum(x, axis)
+    hi, lo = jax.lax.psum(((x >> jnp.uint64(32)).astype(jnp.uint32),
+                           (x & jnp.uint64(0xFFFFFFFF)).astype(jnp.uint32)),
+                          axis)
+    return (hi.astype(jnp.uint64) << jnp.uint64(32)) | lo.astype(jnp.uint64)
 
 
 def _mesh_pool_specs(stk: dict) -> dict:
@@ -828,13 +857,13 @@ def lookup_batch_sharded_mesh(mesh, stk: dict, q: jnp.ndarray,
                 jnp.where(owned, found, zero),
                 jnp.where(owned, gleaf, zero),
                 jnp.where(owned, sid, zero))
-        return tuple(jax.lax.psum(o, MESH_AXIS) for o in outs)
+        return tuple(psum_disjoint(o) for o in outs)
 
-    pay, found, gleaf, sid = shard_map(
+    pay, found, gleaf, sid = jax.shard_map(
         body, mesh=mesh,
         in_specs=(_mesh_pool_specs(stk), PartitionSpec(), PartitionSpec()),
         out_specs=(PartitionSpec(),) * 4,
-        check_rep=False,   # scan/while bodies lack replication rules
+        check_vma=False,   # scan/while bodies lack replication rules
     )(pools, stk["bounds"], q)
     return pay, found.astype(bool), gleaf, sid
 
@@ -864,11 +893,11 @@ def lookup_batch_sharded_mesh_packed(mesh, stk: dict, q_mat: jnp.ndarray,
         gleaf_m = row[:, None] * L + leaf_m
         return pay_m, found_m.astype(jnp.int32), gleaf_m
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(_mesh_pool_specs(stk), PartitionSpec(MESH_AXIS, None)),
         out_specs=(PartitionSpec(MESH_AXIS, None),) * 3,
-        check_rep=False,   # scan/while bodies lack replication rules
+        check_vma=False,   # scan/while bodies lack replication rules
     )(pools, q_mat.astype(jnp.uint64))
 
 
@@ -938,19 +967,18 @@ def scan_batch_sharded_mesh(mesh, stk: dict, q: jnp.ndarray, count: int = 100,
                 valid.astype(jnp.int32))
             leaf = jnp.where(leaf >= 0,
                              jnp.take(chain, leaf, mode="clip"), -1)
-        return (jax.lax.psum(out_k, MESH_AXIS),
-                jax.lax.psum(out_p, MESH_AXIS),
-                jax.lax.psum(out_v, MESH_AXIS))
+        return (psum_disjoint(out_k), psum_disjoint(out_p),
+                psum_disjoint(out_v))
 
     leaf_specs = tuple(
         PartitionSpec(MESH_AXIS, *(None,) * (stk[f].ndim - 1))
         for f in ("leaf_keys", "leaf_pay", "leaf_count"))
-    out_k, out_p, out_v = shard_map(
+    out_k, out_p, out_v = jax.shard_map(
         body, mesh=mesh,
         in_specs=leaf_specs + (PartitionSpec(), PartitionSpec(),
                                PartitionSpec()),
         out_specs=(PartitionSpec(),) * 3,
-        check_rep=False,   # scan/while bodies lack replication rules
+        check_vma=False,   # scan/while bodies lack replication rules
     )(stk["leaf_keys"], stk["leaf_pay"], stk["leaf_count"],
       stk["leaf_next_chain"], gleaf, q)
     return _scan_compact(out_k, out_p, out_v.astype(bool), count)
@@ -978,7 +1006,7 @@ def scan_batch_sharded_overlay_mesh(mesh, stk: dict, ovr: dict,
     ks, ps, vs = scan_batch_sharded_mesh(mesh, stk, q, count=base,
                                          height=height, max_blocks=max_blocks,
                                          qcap=qcap)
-    return _overlay_scan_merge(ks, ps, vs, keys, pays, tombs, q, count)
+    return _overlay_scan_merge(ks, ps, vs, keys, pays, tombs, q, count, hide)
 
 
 @functools.lru_cache(maxsize=8)
@@ -1005,12 +1033,12 @@ def _mesh_install_fn(mesh, ndims: tuple):
             out[f] = jax.lax.dynamic_update_slice(a, row[None], idx)
         return out
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(specs, PartitionSpec(), {f: PartitionSpec()
                                            for f in _DEVICE_FIELDS}),
         out_specs=specs,
-        check_rep=False)
+        check_vma=False)
     return jax.jit(fn, donate_argnums=(0,))
 
 
@@ -1045,13 +1073,12 @@ def mesh_lookup_backend_fns(backend: str, mesh):
     """Mesh twin of :func:`lookup_backend_fns`: the overlay-merged
     point-lookup entry bound to an index mesh, callable as
     ``fn(snap, ovr, q, height=..., qcap=...)``.  "fused" keeps the Pallas
-    kernel per-device-local under shard_map (interpret off-TPU); "jnp" is
-    the bit-exact oracle, as everywhere else."""
+    kernel per-device-local under shard_map; "jnp" is the bit-exact oracle,
+    as everywhere else."""
     b = resolve_read_backend(backend)
     if b == "jnp":
         return functools.partial(lookup_batch_sharded_overlay_mesh, mesh)
     from ..kernels.fused_lookup.ops import (
         fused_lookup_batch_sharded_overlay_mesh)
-    interpret = (b == "fused_interpret" or jax.default_backend() != "tpu")
     return functools.partial(fused_lookup_batch_sharded_overlay_mesh, mesh,
-                             interpret=interpret)
+                             interpret=(b == "fused_interpret"))

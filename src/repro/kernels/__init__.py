@@ -12,10 +12,13 @@ HBM->VMEM DMA selected by a BlockSpec index_map; the per-block binary search
 becomes a whole-block compare-and-reduce on the VPU.
 
 Keys are uint64 in the host index; TPUs have no native 64-bit lanes, so the
-kernels operate on two u32 planes (hi, lo) with lexicographic compares.
+kernels operate on two 32-bit planes (hi, lo) with lexicographic compares.
 
-Kernels are validated in interpret=True mode on CPU (this container has no
-TPU); the pallas_call/BlockSpec structure is the deployable artifact.
+The serving kernels (``fused_lookup``, ``overlay_merge``) compile for a TPU
+v5e (``tests/test_tpu_compile.py`` compiles them for a described chip;
+``chip_smoke.py`` runs them on one against the jnp path).  On the CPU they
+run only when interpret mode is asked for explicitly (``interpret=True``,
+backend ``"fused_interpret"``), which is how the tier-1 tests check them.
 """
 from .leaf_search.ops import leaf_search
 from .inner_probe.ops import inner_probe_lookup

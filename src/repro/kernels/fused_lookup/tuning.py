@@ -21,9 +21,12 @@ shape, instead of being hardcoded in the kernel:
 
 The gather implementation is tied to the execution mode: interpret mode
 (CPU) uses ``jnp.take`` directly, while a compiled TPU lowering needs the
-one-hot compare-and-reduce idiom of the sibling kernels (``"onehot"``).
-One-hot gathers materialize a (qb, rows) mask, so on compiled backends the
-persistent strategy is only picked for small leaf pools.
+one-hot compare-and-reduce idiom (``"onehot"``) for per-query values and a
+per-query dynamic row load (or DMA) for whole rows.  A one-hot gather
+materializes a (qb, X) mask over the gathered pool, so the compiled kernel
+only takes pools up to ``ONEHOT_LANE_CAP`` entries wide, and its resident
+pools must fit the VMEM budget: :func:`choose_strategy` raises for any
+geometry the compiled kernel cannot take.
 
 ``autotune`` runs a cached sweep over candidate tile sizes with a
 caller-supplied measurement function; the cache is keyed by geometry so the
@@ -37,9 +40,9 @@ import dataclasses
 # outputs, and the (qb, C) search temporaries.
 DEFAULT_VMEM_BUDGET = 8 * 2 ** 20
 
-# one-hot row gathers materialize a (qb, rows) mask — cap the rows above
-# which the persistent leaf gather is considered unlowerable-at-speed
-ONEHOT_PERSISTENT_ROW_CAP = 4096
+# one-hot gathers materialize a (qb, X) int32 mask per gather: cap X so a
+# few such temporaries fit the kernel's scoped VMEM next to the pools
+ONEHOT_LANE_CAP = 8192
 
 QB_CANDIDATES = (64, 128, 256)
 DEFAULT_QB = 128  # one VPU lane row per u32 plane
@@ -68,10 +71,10 @@ class PoolGeometry:
     @property
     def inner_bytes(self) -> int:
         """Resident bytes of the non-leaf pools as the kernel packs them:
-        u32 planes for keys/payloads, i32 for links/tags, f64 models."""
+        32-bit planes for keys/payloads, links/tags and the f32 models."""
         s = self.num_shards
-        slots = s * self.slot_pool * (4 * 4 + 2 * 4)      # 4 i32 rows + 2 u32
-        nodes = s * self.node_pool * (3 * 4 + 2 * 8)      # 3 i32 rows + 2 f64
+        slots = s * self.slot_pool * (4 * 4 + 2 * 4)      # 4 i32 rows + 2 keys
+        nodes = s * self.node_pool * (5 * 4 + 2 * 4)      # 5 i32 rows + 2 f32
         pa = s * self.pa_pool * self.pa_cap * (2 * 4 + 4)  # key planes + ptrs
         bt = s * self.bt_pool * self.bt_cap * (2 * 4 + 4)
         return slots + nodes + pa + bt
@@ -88,6 +91,13 @@ class PoolGeometry:
     @property
     def leaf_rows(self) -> int:
         return self.num_shards * self.leaf_pool
+
+    @property
+    def onehot_widths(self) -> dict:
+        """Widths of the pools the compiled kernel gathers from one-hot."""
+        s = self.num_shards
+        return {"slots": s * self.slot_pool, "nodes": s * self.node_pool,
+                "overlay": self.overlay_bucket}
 
     # ---------------------------------------------------------- constructors
     @classmethod
@@ -140,14 +150,27 @@ def choose_strategy(geom: PoolGeometry, *, interpret: bool,
     ==========================  =============  ==========================
     inner+leaf+overlay <= VMEM  persistent     one load, zero per-query DMA
     leaf pool > VMEM budget     looped         1 row DMA/query, exact fetch
-    onehot + many leaf rows     looped         (qb, rows) mask too large
     ==========================  =============  ==========================
+
+    For the compiled kernel (``interpret=False``) it raises ``ValueError``
+    when no strategy can compile: the inner pools and overlay alone over the
+    VMEM budget, or a one-hot gathered pool wider than ``ONEHOT_LANE_CAP``.
     """
     gather = "take" if interpret else "onehot"
     resident = geom.inner_bytes + geom.leaf_bytes + geom.overlay_bytes
     leaf = "persistent" if resident <= vmem_budget else "looped"
-    if gather == "onehot" and geom.leaf_rows > ONEHOT_PERSISTENT_ROW_CAP:
-        leaf = "looped"
+    if not interpret:
+        fixed = geom.inner_bytes + geom.overlay_bytes
+        if fixed > vmem_budget:
+            raise ValueError(
+                f"compiled fused kernel: resident inner pools + overlay "
+                f"need {fixed} B of VMEM, over the {vmem_budget} B budget")
+        wide = {k: v for k, v in geom.onehot_widths.items()
+                if v > ONEHOT_LANE_CAP}
+        if wide:
+            raise ValueError(
+                f"compiled fused kernel: one-hot gathers over {wide} "
+                f"exceed {ONEHOT_LANE_CAP} lanes")
     qb = DEFAULT_QB
     # a tiny mirror does not fill a 128-lane tile with useful work
     if geom.leaf_rows * geom.leaf_cap < DEFAULT_QB:

@@ -8,28 +8,34 @@ semantics (sorted union, batch wins on key collisions, tombstones replayed
 as entries) realized without any device-side sort:
 
 * a one-time *rank pass* per shard (grid step 0, persisted in VMEM scratch)
-  computes each survivor's output position by rank arithmetic — for an
-  overlay entry, its rank among surviving overlay keys plus the count of
-  live batch keys below it; for a batch entry, its rank among live batch
-  entries plus the count of *surviving* overlay keys below it.  Overwritten
-  overlay keys and padding get a -1 sentinel.  Positions of survivors and
-  batch entries interleave into one dense sorted run by construction.
+  computes each survivor's output position by rank arithmetic.  Both packs
+  hold their live entries as a sorted prefix (u64-max padding sorts last),
+  so ranks need no prefix sum: overlay entry ``i`` survives unless the batch
+  overwrites it and lands at ``i`` plus the number of *new* batch keys below
+  it; live batch entry ``j`` lands at ``j`` plus the number of surviving
+  overlay keys below it.  Overwritten overlay keys and padding get a -1
+  sentinel.  Positions of survivors and batch entries interleave into one
+  dense sorted run by construction.
 * each subsequent grid step emits one output tile by one-hot matching the
   position arrays against its slot indices and compare-and-reducing the
   value planes (the ``overlay_probe`` extraction idiom); unmatched slots
   become u64-max padding.
 
-The rank pass builds (Ca, Cb) compare matrices, so the batch side must stay
-small — which it is by construction: Cb is the power-of-two bucket of one
-step's writes, while reseed-sized transfers take the host path.  VMEM
-working set: 10 resident (1, C) planes + 2 scratch rows + one (OB, Ca)
-match matrix per tile (~4 MB at Ca=4096, OB=256).
+The rank pass builds (chunk, Cb) compare matrices over chunks of the
+overlay, so the batch side must stay small — which it is by construction:
+Cb is the power-of-two bucket of one step's writes, while reseed-sized
+transfers take the host path.  VMEM working set: 10 resident (1, C) planes +
+2 scratch rows + one (OB, Ca) match matrix per tile (~4 MB at Ca=4096,
+OB=256).
 
-uint64 keys/payloads travel as two u32 planes (no 64-bit lanes on TPU);
-0xFFFFFFFF/0xFFFFFFFF planes == u64-max padding never survives as a live
-key.  The stacked (S, ·) form merges every shard of a sharded engine in one
-launch; grid order is row-major so the rank scratch is recomputed exactly
-once per shard row.
+Layout for the TPU compiler (Mosaic): u64 keys travel as (hi, lo) int32
+planes with the sign bit flipped ("biased": a signed compare orders them as
+unsigned), payloads as raw int32 bits; u64-max padding is ``PAD`` in both
+key planes.  Each (S, C) plane enters as (S, 1, C) so a shard row is a
+block whose last two dimensions equal the array's.  Trace and lower it with
+x64 off (``fused_lookup.run_x32``).  The stacked (S, ·) form merges every
+shard of a sharded engine in one launch; grid order is row-major so the rank
+scratch is recomputed exactly once per shard row.
 """
 from __future__ import annotations
 
@@ -40,13 +46,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..fused_lookup.fused_lookup import VMEM_LIMIT_BYTES
+
 # python int (not a jnp scalar): kernel bodies must not capture traced
 # constants, and an int folds into the trace as a literal
-UM32 = 0xFFFFFFFF
+PAD = 2 ** 31 - 1         # biased plane of u64-max padding (raw 0xFFFFFFFF)
+
+# overlay rows per rank-pass chunk: bounds the (chunk, Cb) compare matrices
+RANK_CHUNK = 512
 
 
 def _lt(ah, al, bh, bl):
-    """(ah,al) < (bh,bl) lexicographic on u32 planes."""
+    """(ah,al) < (bh,bl) on biased planes: exact unsigned u64 compare."""
     return (ah < bh) | ((ah == bh) & (al < bl))
 
 
@@ -56,52 +67,74 @@ def _kernel(akh_ref, akl_ref, aph_ref, apl_ref, atb_ref,   # (1, Ca) overlay
             pa_ref, pb_ref,                                # scratch positions
             *, ob: int):
     t = pl.program_id(1)
-    kh = akh_ref[0, :]
-    kl = akl_ref[0, :]
-    bh = bkh_ref[0, :]
-    bl = bkl_ref[0, :]
+    Ca = akh_ref.shape[1]
+    Cb = bkh_ref.shape[1]
 
     @pl.when(t == 0)
     def _rank_pass():
-        la = ~((kh == UM32) & (kl == UM32))
-        lb = ~((bh == UM32) & (bl == UM32))
-        # overlay keys overwritten by the batch (last-writer-wins upsert)
-        eq = (kh[:, None] == bh[None, :]) & (kl[:, None] == bl[None, :])
-        in_b = jnp.sum((eq & lb[None, :]).astype(jnp.int32), axis=1) > 0
-        surv = la & ~in_b
-        # live batch keys strictly below each overlay key
-        blt = _lt(bh[None, :], bl[None, :], kh[:, None], kl[:, None])
-        nb_lt = jnp.sum((blt & lb[None, :]).astype(jnp.int32), axis=1)
-        surv_i = surv.astype(jnp.int32)
-        rank_a = jnp.cumsum(surv_i.reshape(1, -1), axis=1)[0] - surv_i
-        pa_ref[0, :] = jnp.where(surv, rank_a + nb_lt, -1).astype(jnp.int32)
-        # surviving overlay keys strictly below each batch key
-        alt = _lt(kh[None, :], kl[None, :], bh[:, None], bl[:, None])
-        na_lt = jnp.sum((alt & surv[None, :]).astype(jnp.int32), axis=1)
-        lb_i = lb.astype(jnp.int32)
-        rank_b = jnp.cumsum(lb_i.reshape(1, -1), axis=1)[0] - lb_i
-        pb_ref[0, :] = jnp.where(lb, rank_b + na_lt, -1).astype(jnp.int32)
+        bh = bkh_ref[...]
+        bl = bkl_ref[...]
+        lb = ~((bh == PAD) & (bl == PAD))                       # (1, Cb)
+        ch = min(Ca, RANK_CHUNK)
+
+        def chunk(c):
+            """Overlay rows [c, c+ch) as (ch, 1) columns + their compares
+            against the batch row: eq (raw key match) and b < a."""
+            ah = akh_ref[:, c:c + ch].reshape(ch, 1)
+            al = akl_ref[:, c:c + ch].reshape(ch, 1)
+            eq = (ah == bh) & (al == bl)
+            return ah, al, eq, _lt(bh, bl, ah, al)
+
+        # pass 1: batch keys that overwrite a live overlay key
+        in_a = jnp.zeros((1, Cb), jnp.int32)
+        for c in range(0, Ca, ch):
+            _, _, eq, _ = chunk(c)
+            in_a = in_a + jnp.sum((eq & lb).astype(jnp.int32), axis=0,
+                                  keepdims=True, dtype=jnp.int32)
+        new_b = lb & (in_a == 0)
+        # pass 2: overlay positions; surviving overlay keys below each batch
+        # key for the batch positions
+        na_lt = jnp.zeros((1, Cb), jnp.int32)
+        for c in range(0, Ca, ch):
+            ah, al, eq, blt = chunk(c)
+            la = ~((ah == PAD) & (al == PAD))                   # (ch, 1)
+            in_b = jnp.sum((eq & lb).astype(jnp.int32), axis=1,
+                           keepdims=True, dtype=jnp.int32) > 0
+            surv = la & ~in_b
+            row = c + jax.lax.broadcasted_iota(jnp.int32, (ch, 1), 0)
+            pos = row + jnp.sum((blt & new_b).astype(jnp.int32), axis=1,
+                                keepdims=True, dtype=jnp.int32)
+            pa_ref[:, c:c + ch] = jnp.where(surv, pos, -1).reshape(1, ch)
+            below = ~blt & ~eq & surv                           # a < b
+            na_lt = na_lt + jnp.sum(below.astype(jnp.int32), axis=0,
+                                    keepdims=True, dtype=jnp.int32)
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, Cb), 1)
+        pb_ref[...] = jnp.where(lb, col + na_lt, -1)
 
     # one-hot match this tile's slots against the position arrays; the -1
     # sentinel (dropped entries) never matches a slot index >= 0
     slot = t * ob + jax.lax.broadcasted_iota(jnp.int32, (ob, 1), 0)
-    sel_a = pa_ref[0, :][None, :] == slot          # (OB, Ca)
-    sel_b = pb_ref[0, :][None, :] == slot          # (OB, Cb)
-    got = (jnp.sum(sel_a.astype(jnp.int32), axis=1)
-           + jnp.sum(sel_b.astype(jnp.int32), axis=1)) > 0
+    sel_a = pa_ref[...] == slot                    # (OB, Ca)
+    sel_b = pb_ref[...] == slot                    # (OB, Cb)
+    got = (jnp.sum(sel_a.astype(jnp.int32), axis=1, keepdims=True,
+                   dtype=jnp.int32)
+           + jnp.sum(sel_b.astype(jnp.int32), axis=1, keepdims=True,
+                     dtype=jnp.int32)) > 0
 
-    def red_u(sel, v):
-        return jnp.sum(jnp.where(sel, v[None, :], jnp.uint32(0)), axis=1,
-                       dtype=jnp.uint32)
+    def red(a_ref, b_ref):
+        def one(sel, ref):
+            return jnp.sum(jnp.where(sel, ref[...], 0), axis=1,
+                           keepdims=True, dtype=jnp.int32)
+        return one(sel_a, a_ref) + one(sel_b, b_ref)   # (OB, 1)
 
-    okh_ref[0, :] = jnp.where(got, red_u(sel_a, kh) + red_u(sel_b, bh), UM32)
-    okl_ref[0, :] = jnp.where(got, red_u(sel_a, kl) + red_u(sel_b, bl), UM32)
-    oph_ref[0, :] = red_u(sel_a, aph_ref[0, :]) + red_u(sel_b, bph_ref[0, :])
-    opl_ref[0, :] = red_u(sel_a, apl_ref[0, :]) + red_u(sel_b, bpl_ref[0, :])
-    otb_ref[0, :] = (
-        jnp.sum(jnp.where(sel_a, atb_ref[0, :], 0), axis=1, dtype=jnp.int32)
-        + jnp.sum(jnp.where(sel_b, btb_ref[0, :], 0), axis=1,
-                  dtype=jnp.int32))
+    def put(ref, col):
+        ref[...] = col.reshape(1, ob)
+
+    put(okh_ref, jnp.where(got, red(akh_ref, bkh_ref), PAD))
+    put(okl_ref, jnp.where(got, red(akl_ref, bkl_ref), PAD))
+    put(oph_ref, red(aph_ref, bph_ref))
+    put(opl_ref, red(apl_ref, bpl_ref))
+    put(otb_ref, red(atb_ref, btb_ref))
 
 
 @functools.partial(jax.jit, static_argnames=("cap_out", "interpret"))
@@ -111,27 +144,34 @@ def overlay_merge_planes(akh: jnp.ndarray, akl: jnp.ndarray,
                          bkh: jnp.ndarray, bkl: jnp.ndarray,
                          bph: jnp.ndarray, bpl: jnp.ndarray,
                          btb: jnp.ndarray, *,
-                         cap_out: int, interpret: bool = True):
-    """Stacked plane merge: overlay planes (S, Ca) u32 / tomb (S, Ca) i32
-    updated by batch planes (S, Cb); returns five (S, cap_out) planes
-    (keys hi/lo, payload hi/lo, tombstone i32).  ``cap_out`` must be a
-    power of two covering each shard's merged live count."""
+                         cap_out: int, interpret: bool):
+    """Stacked plane merge: overlay int32 planes (S, Ca) (keys biased,
+    payload bits, tombstone 0/1) updated by batch planes (S, Cb); returns
+    five (S, cap_out) int32 planes in the same layout.  ``cap_out`` must be
+    a power of two covering each shard's merged live count; Ca a multiple of
+    ``RANK_CHUNK`` when larger than it."""
     S, Ca = akh.shape
     Cb = bkh.shape[1]
     ob = min(cap_out, 256)
     grid = (S, cap_out // ob)
-    aspec = pl.BlockSpec((1, Ca), lambda s, t: (s, 0))
-    bspec = pl.BlockSpec((1, Cb), lambda s, t: (s, 0))
-    ospec = pl.BlockSpec((1, ob), lambda s, t: (s, t))
-    return pl.pallas_call(
-        functools.partial(_kernel, ob=ob),
-        grid=grid,
-        in_specs=[aspec] * 5 + [bspec] * 5,
-        out_specs=[ospec] * 5,
-        out_shape=[jax.ShapeDtypeStruct((S, cap_out), jnp.uint32)] * 4
-        + [jax.ShapeDtypeStruct((S, cap_out), jnp.int32)],
-        scratch_shapes=[pltpu.VMEM((1, Ca), jnp.int32),
-                        pltpu.VMEM((1, Cb), jnp.int32)],
-        interpret=interpret,
-    )(akh, akl, aph, apl, atb.astype(jnp.int32),
-      bkh, bkl, bph, bpl, btb.astype(jnp.int32))
+    aspec = pl.BlockSpec((None, 1, Ca), lambda s, t: (s, 0, 0))
+    bspec = pl.BlockSpec((None, 1, Cb), lambda s, t: (s, 0, 0))
+    ospec = pl.BlockSpec((None, 1, ob), lambda s, t: (s, 0, t))
+    rows = [p.reshape(S, 1, -1) for p in (akh, akl, aph, apl, atb,
+                                           bkh, bkl, bph, bpl, btb)]
+    with jax.enable_x64(False):      # literals stay 32-bit (run_x32)
+        out = pl.pallas_call(
+            functools.partial(_kernel, ob=ob),
+            grid=grid,
+            in_specs=[aspec] * 5 + [bspec] * 5,
+            out_specs=[ospec] * 5,
+            out_shape=[jax.ShapeDtypeStruct((S, 1, cap_out), jnp.int32)] * 5,
+            scratch_shapes=[pltpu.VMEM((1, Ca), jnp.int32),
+                            pltpu.VMEM((1, Cb), jnp.int32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=VMEM_LIMIT_BYTES),
+            interpret=interpret,
+            name="aulid_overlay_merge",
+        )(*rows)
+    return tuple(o.reshape(S, cap_out) for o in out)

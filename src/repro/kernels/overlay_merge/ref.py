@@ -1,7 +1,7 @@
 """Pure-jnp oracle for the overlay-merge kernel: identical plane semantics
-(lexicographic u32-plane compares, rank arithmetic, -1/drop sentinels),
-realized with gather-free broadcasting and a scatter instead of the kernel's
-tiled one-hot extraction."""
+(lexicographic biased-plane compares, rank arithmetic, -1/drop sentinels),
+realized with gather-free broadcasting, prefix sums and a scatter instead of
+the kernel's chunked rank pass and tiled one-hot extraction."""
 from __future__ import annotations
 
 import functools
@@ -9,13 +9,13 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .overlay_merge import UM32, _lt
+from .overlay_merge import PAD, _lt
 
 
 def _merge_ref_flat(akh, akl, aph, apl, atb,
                     bkh, bkl, bph, bpl, btb, *, cap_out: int):
-    la = ~((akh == UM32) & (akl == UM32))
-    lb = ~((bkh == UM32) & (bkl == UM32))
+    la = ~((akh == PAD) & (akl == PAD))
+    lb = ~((bkh == PAD) & (bkl == PAD))
     eq = (akh[:, None] == bkh[None, :]) & (akl[:, None] == bkl[None, :])
     in_b = jnp.any(eq & lb[None, :], axis=1)
     surv = la & ~in_b
@@ -35,11 +35,11 @@ def _merge_ref_flat(akh, akl, aph, apl, atb,
         return (out.at[pos_a].set(va, mode="drop")
                 .at[pos_b].set(vb, mode="drop"))
 
-    return (scat(UM32, akh, bkh, jnp.uint32),
-            scat(UM32, akl, bkl, jnp.uint32),
-            scat(0, aph, bph, jnp.uint32),
-            scat(0, apl, bpl, jnp.uint32),
-            scat(0, atb.astype(jnp.int32), btb.astype(jnp.int32), jnp.int32))
+    return (scat(PAD, akh, bkh, jnp.int32),
+            scat(PAD, akl, bkl, jnp.int32),
+            scat(0, aph, bph, jnp.int32),
+            scat(0, apl, bpl, jnp.int32),
+            scat(0, atb, btb, jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("cap_out",))
@@ -48,5 +48,4 @@ def overlay_merge_ref(akh, akl, aph, apl, atb,
     """Stacked (S, ·) plane merge — same signature/returns as
     ``overlay_merge_planes`` minus the interpret switch."""
     fn = functools.partial(_merge_ref_flat, cap_out=cap_out)
-    return jax.vmap(fn)(akh, akl, aph, apl, atb.astype(jnp.int32),
-                        bkh, bkl, bph, bpl, btb.astype(jnp.int32))
+    return jax.vmap(fn)(akh, akl, aph, apl, atb, bkh, bkl, bph, bpl, btb)

@@ -82,6 +82,7 @@ class ShardedIndexEngine(BaseIndexEngine):
                                    resolve_read_backend,
                                    scan_batch_sharded_overlay,
                                    stacked_device_arrays,
+                                   stacked_host_arrays,
                                    update_stacked_shard,
                                    update_stacked_shard_mesh)
         super().__init__()
@@ -98,14 +99,15 @@ class ShardedIndexEngine(BaseIndexEngine):
             # mesh placement (DESIGN.md §13): stacked pools shard their
             # leading axis across the index mesh, reads/installs go through
             # the per-device shard_map twins, and every stack build places
-            # its pools before serving from them
+            # its pools from host memory straight onto their shardings — no
+            # device ever holds the whole stack
             from ..parallel.index_placement import place_stacked
             self._mesh_lookup = mesh_lookup_backend_fns(backend, mesh)
             self._lookup = self._mesh_lookup_entry
             self._scan = self._mesh_scan_entry
             self._stacked_device_arrays = (
                 lambda sdi, version=0: place_stacked(
-                    stacked_device_arrays(sdi, version), mesh))
+                    stacked_host_arrays(sdi, version), mesh))
             self._update_stacked_shard = functools.partial(
                 update_stacked_shard_mesh, mesh)
         self.part = part
@@ -660,8 +662,10 @@ class ShardedIndexEngine(BaseIndexEngine):
         self._pack_struct = struct
         self.overlay_reseeds += 1
         self.write_h2d_bytes += int(pack.nbytes)
-        ovr = {"ov_pack": jnp.asarray(pack), "ov_token": new_snap_token()}
-        if self.mesh is not None:
+        ovr = {"ov_pack": pack, "ov_token": new_snap_token()}
+        if self.mesh is None:
+            ovr["ov_pack"] = jnp.asarray(pack)
+        else:
             # committed replication: later device-side delta merges inherit
             # the replicated sharding instead of re-broadcasting per dispatch
             from ..parallel.index_placement import place_overlay_pack

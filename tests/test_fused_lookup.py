@@ -267,10 +267,16 @@ class TestTuning:
         # leaf pool past the VMEM budget -> looped
         big = self._geom(leaf_pool=2**20, leaf_cap=32)
         assert choose_strategy(big, interpret=True).leaf == "looped"
-        # onehot mask too large even under budget -> looped
-        wide = self._geom(leaf_pool=tuning.ONEHOT_PERSISTENT_ROW_CAP + 1)
-        assert choose_strategy(wide, interpret=False).leaf == "looped"
+        assert choose_strategy(big, interpret=False).leaf == "looped"
+        # the compiled kernel refuses what it cannot take; interpret does not
+        wide = self._geom(slot_pool=tuning.ONEHOT_LANE_CAP + 1)
+        with pytest.raises(ValueError, match="one-hot"):
+            choose_strategy(wide, interpret=False)
         assert choose_strategy(wide, interpret=True).leaf == "persistent"
+        fat = self._geom(pa_pool=2**16, pa_cap=64)
+        with pytest.raises(ValueError, match="VMEM"):
+            choose_strategy(fat, interpret=False)
+        assert choose_strategy(fat, interpret=True).leaf == "looped"
         # tiny mirror -> smallest tile
         tiny = self._geom(leaf_pool=4, leaf_cap=8)
         assert choose_strategy(tiny, interpret=True).qb == min(
@@ -323,10 +329,14 @@ class TestBackendDispatch:
     def test_resolve(self):
         assert resolve_read_backend("jnp") == "jnp"
         assert resolve_read_backend("fused_interpret") == "fused_interpret"
-        assert resolve_read_backend("auto") in ("jnp", "fused")
+        assert resolve_read_backend("auto") == "jnp"   # on every platform
         import jax
         if jax.default_backend() != "tpu":
-            assert resolve_read_backend("auto") == "jnp"
+            # the compiled kernel never degrades to interpret mode
+            with pytest.raises(ValueError, match="fused_interpret"):
+                resolve_read_backend("fused")
+            with pytest.raises(ValueError):
+                lookup_backend_fns("fused")
         with pytest.raises(ValueError):
             resolve_read_backend("cuda_graphs")
         with pytest.raises(ValueError):
