@@ -39,7 +39,7 @@ kernel is traced and lowered with x64 off (:func:`run_x32`): Mosaic has no
 
 The FMCD prediction needs no 64-bit type: the host re-anchors each node's
 model as ``v = slope * (q - K) + c`` with an integer key offset ``K``
-(``ops.node_anchor_planes``), the kernel forms ``q - K`` exactly in u32
+(``ops.node_anchor_models``), the kernel forms ``q - K`` exactly in u32
 planes and evaluates ``v`` in f32.  The host checks per mirror that this ``v``
 is within one slot of the jnp path's f64 one; the kernel then starts its
 successor-chain walk ``PRED_MARGIN`` slots left of ``floor(v)`` (the jnp path

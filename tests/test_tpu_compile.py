@@ -8,8 +8,9 @@ blocks, 64-bit types in a kernel, VMEM or HBM overflow).  Nothing runs.
 
 Covered: the jitted jnp read and scan steps of the sharded engine at the
 pool widths of the 200M-key, 16-shard deployment ``chip_smoke.py`` loads,
-on one chip and as the mesh engine over four; the fused lookup kernel with both leaf strategies at a geometry the tuner
-admits; the overlay-merge kernel.
+on one chip and as the mesh engine over four; the fused lookup kernel with
+both leaf strategies at a geometry the tuner admits; the overlay-merge
+kernel.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and the test runner's
