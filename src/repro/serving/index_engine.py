@@ -34,6 +34,7 @@ engine stays the S=1 special case.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Optional
@@ -46,6 +47,24 @@ from ..core.device_index import (DeviceIndex, build_device_index,
                                  refresh_device_index)
 
 MIN_SCAN_BUCKET = 8
+
+# phases of the engine step, each timed into ``phase_s[name]`` (reported by
+# ``stats()`` as ``<name>_s``) and traced as the profiler span
+# ``aulid.<name>``: the swap install on the request path, the per-write host
+# loop, the overlay-pack refresh, the read batches' dispatch / wait on the
+# device / per-request unpacking, and the background compaction build (timed
+# on its pool thread, added at install).  The whole step is ``step_seconds``.
+PHASES = ("install", "write_apply", "write_host", "read_dispatch",
+          "read_wait", "read_unpack", "compact_build")
+
+
+def phase_span(name: str):
+    """Profiler span ``aulid.<name>``: a TraceMe on the host plane, so it
+    shares the device planes' clock in a ``jax.profiler`` trace; near free
+    while no trace is running."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(f"aulid.{name}")
+
 
 # shared background-build pool of the double-buffered compaction path
 # (DESIGN.md §11); one per process — builds are host-CPU + transfer bound and
@@ -118,7 +137,6 @@ class IndexShard:
     ov_merge_fn: Optional[object] = None
     ov_struct: Optional[tuple] = None
     write_h2d_bytes: int = 0
-    write_host_s: float = 0.0
     overlay_merges: int = 0
     overlay_reseeds: int = 0
 
@@ -231,7 +249,8 @@ class IndexShard:
         After a fast-path refresh only the touched leaf rows are re-uploaded
         (``update_leaf_rows``); a full rebuild re-transfers every pool.  When
         this shard serves from a stacked mirror (``arrs is None``) the device
-        update is the owner engine's job (``restack_shard``)."""
+        update is the owner engine's job (``restack_shard``), as is the
+        refresh of the (now empty) overlay pack."""
         assert self.frozen_overlay is None, \
             "sync compact during in-flight compaction (drain first)"
         old = self.di
@@ -243,8 +262,6 @@ class IndexShard:
             else:
                 self.arrs = device_arrays(self.di)
         self.overlay.clear()
-        if self.ov_arrs is not None:
-            self.refresh_overlay_arrays()
         self.compactions += 1
 
     def refresh_overlay_arrays(self) -> None:
@@ -264,7 +281,6 @@ class IndexShard:
         change — freeze, finish_swap, abort_swap, or a clear() (which takes
         a fresh uid) — rebuilds the pack from the host state, and
         ``mark_synced`` discards the now-moot pending deltas."""
-        t0 = time.perf_counter()
         struct = (self.overlay.uid,
                   self.frozen_overlay.uid if self.frozen_overlay else 0)
         if (self.ov_merge_fn is not None and self.ov_arrs is not None
@@ -278,7 +294,6 @@ class IndexShard:
                     self.ov_arrs, batch, cap_out, merge_fn=self.ov_merge_fn)
                 self.write_h2d_bytes += nbytes
                 self.overlay_merges += 1
-            self.write_host_s += time.perf_counter() - t0
             return
         from ..core.lookup import overlay_arrays, overlay_arrays_merged
         self.overlay.mark_synced()
@@ -291,7 +306,6 @@ class IndexShard:
         self.ov_struct = struct
         self.overlay_reseeds += 1
         self.write_h2d_bytes += int(self.ov_arrs["ov_pack"].nbytes)
-        self.write_host_s += time.perf_counter() - t0
 
     def overlay_live(self) -> int:
         """Upper bound on live served-overlay entries (scan ``ov_bound``):
@@ -317,9 +331,8 @@ class BaseIndexEngine:
         self.steps = 0
         self.reads_served = 0
         self.writes_applied = 0
-        self.read_batch_sizes: list[int] = []
-        self.serve_seconds = 0.0
         self.step_seconds: list[float] = []   # per-step latency (p99 source)
+        self.phase_s = dict.fromkeys(PHASES, 0.0)
         # first-seen read specializations — static args (count bucket /
         # ov_bound / height) PLUS every device operand's shape, i.e. the
         # jit cache key: each new combo compiles a fresh read variant, so
@@ -328,6 +341,18 @@ class BaseIndexEngine:
         # shapes changed); a swap install re-uses them (shapes kept).
         self._read_shapes: set[tuple] = set()
         self.read_shape_misses = 0
+
+    @contextlib.contextmanager
+    def _phase(self, name: str):
+        """Time the block into ``phase_s[name]`` and trace it as the span
+        ``aulid.<name>``. Request thread only: a pool-thread job times itself
+        under ``phase_span`` and returns its seconds for the install to add."""
+        t0 = time.perf_counter()
+        try:
+            with phase_span(name):
+                yield
+        finally:
+            self.phase_s[name] += time.perf_counter() - t0
 
     def _note_read_shape(self, *statics) -> None:
         sig = tuple(sorted(
@@ -399,17 +424,19 @@ class BaseIndexEngine:
     # ------------------------------------------------------------- read path
     def _serve_gets(self, gets: list[IndexRequest]) -> None:
         import jax.numpy as jnp
-        q = jnp.asarray(pad_queries([r.key for r in gets]))
-        self._note_read_shape("get", q.shape[0])
-        pay, found, _ = self._lookup(self._snap(), self._ov(), q,
-                                     height=self._height())
-        pay = np.asarray(pay)
-        found = np.asarray(found)
-        for i, r in enumerate(gets):
-            r.result = int(pay[i]) if bool(found[i]) else None
-            r.done = True
+        with self._phase("read_dispatch"):
+            q = jnp.asarray(pad_queries([r.key for r in gets]))
+            self._note_read_shape("get", q.shape[0])
+            pay, found, _ = self._lookup(self._snap(), self._ov(), q,
+                                         height=self._height())
+        with self._phase("read_wait"):
+            pay = np.asarray(pay)
+            found = np.asarray(found)
+        with self._phase("read_unpack"):
+            for i, r in enumerate(gets):
+                r.result = int(pay[i]) if bool(found[i]) else None
+                r.done = True
         self.reads_served += len(gets)
-        self.read_batch_sizes.append(len(gets))
 
     def _serve_scans(self, scans: list[IndexRequest]) -> None:
         import jax.numpy as jnp
@@ -420,18 +447,22 @@ class BaseIndexEngine:
         # scales with how full the overlay IS, not its padded capacity
         ov_bound = next_pow2(max(self._overlay_live(), MIN_SCAN_BUCKET))
         for bucket, grp in sorted(by_bucket.items()):
-            q = jnp.asarray(pad_queries([r.key for r in grp]))
-            self._note_read_shape("scan", q.shape[0], bucket, ov_bound)
-            ks, ps, valid = self._scan(self._snap(), self._ov(), q,
-                                       count=bucket, height=self._height(),
-                                       ov_bound=ov_bound)
-            ks, ps, valid = map(np.asarray, (ks, ps, valid))
-            for i, r in enumerate(grp):
-                n = min(int(valid[i].sum()), r.count or 100)
-                r.result = list(zip(ks[i][:n].tolist(), ps[i][:n].tolist()))
-                r.done = True
+            with self._phase("read_dispatch"):
+                q = jnp.asarray(pad_queries([r.key for r in grp]))
+                self._note_read_shape("scan", q.shape[0], bucket, ov_bound)
+                ks, ps, valid = self._scan(self._snap(), self._ov(), q,
+                                           count=bucket,
+                                           height=self._height(),
+                                           ov_bound=ov_bound)
+            with self._phase("read_wait"):
+                ks, ps, valid = map(np.asarray, (ks, ps, valid))
+            with self._phase("read_unpack"):
+                for i, r in enumerate(grp):
+                    n = min(int(valid[i].sum()), r.count or 100)
+                    r.result = list(zip(ks[i][:n].tolist(),
+                                        ps[i][:n].tolist()))
+                    r.done = True
             self.reads_served += len(grp)
-            self.read_batch_sizes.append(len(grp))
 
     # ------------------------------------------------------------------ step
     def step(self) -> int:
@@ -445,9 +476,10 @@ class BaseIndexEngine:
         writes = [r for r in batch if r.op in ("insert", "delete")]
         gets = [r for r in batch if r.op == "get"]
         scans = [r for r in batch if r.op == "scan"]
-        for r in writes:
-            self._apply_write(r)
         if writes:
+            with self._phase("write_apply"):
+                for r in writes:
+                    self._apply_write(r)
             self._after_writes()
         if gets:
             self._serve_gets(gets)
@@ -455,9 +487,7 @@ class BaseIndexEngine:
             self._serve_scans(scans)
         self._end_step()
         self.steps += 1
-        dt = time.perf_counter() - t0
-        self.serve_seconds += dt
-        self.step_seconds.append(dt)
+        self.step_seconds.append(time.perf_counter() - t0)
         return len(batch)
 
     def run(self) -> int:
@@ -468,18 +498,12 @@ class BaseIndexEngine:
 
     # ----------------------------------------------------------------- stats
     def stats(self) -> dict:
-        ops = self.reads_served + self.writes_applied
         return {
             "steps": self.steps,
             "reads_served": self.reads_served,
             "writes_applied": self.writes_applied,
-            "mean_read_batch": (float(np.mean(self.read_batch_sizes))
-                                if self.read_batch_sizes else 0.0),
-            "throughput_ops_s": (ops / self.serve_seconds
-                                 if self.serve_seconds else 0.0),
-            "p99_step_s": (float(np.percentile(self.step_seconds, 99))
-                           if self.step_seconds else 0.0),
             "read_shape_misses": self.read_shape_misses,
+            **{f"{k}_s": v for k, v in self.phase_s.items()},
         }
 
 
@@ -556,12 +580,18 @@ class IndexEngine(BaseIndexEngine):
     def compact(self) -> None:
         self.drain_compactions()
         self.shard.compact()
+        self._refresh_pack()
+
+    def _refresh_pack(self) -> None:
+        with self._phase("write_host"):
+            self.shard.refresh_overlay_arrays()
 
     def _maybe_compact(self) -> bool:
         if not (self.auto_compact and self.shard.needs_compaction(self.gamma)):
             return False
         if not self.async_compact:
             self.shard.compact()
+            self._refresh_pack()
             return True
         if self._inflight is None:     # one build in flight per engine
             self.shard.freeze()
@@ -572,35 +602,41 @@ class IndexEngine(BaseIndexEngine):
         """Background build+upload (DESIGN.md §11): refresh the host mirror
         from the (frozen) index and prepare the full device pack off the
         request path.  Only reads foreground state the in-flight window
-        freezes (``idx``, ``di``, ``arrs``)."""
+        freezes (``idx``, ``di``, ``arrs``).  Returns its own seconds too:
+        the install adds them on the request thread."""
         from ..core.lookup import device_arrays, update_leaf_rows
-        shard = self.shard
-        old = shard.di
-        di = refresh_device_index(shard.idx, old)
-        if di is old and shard.arrs is not None:
-            arrs = update_leaf_rows(shard.arrs, di)
-        else:
-            arrs = device_arrays(di)
-        return di, arrs
+        t0 = time.perf_counter()
+        with phase_span("compact_build"):
+            shard = self.shard
+            old = shard.di
+            di = refresh_device_index(shard.idx, old)
+            if di is old and shard.arrs is not None:
+                arrs = update_leaf_rows(shard.arrs, di)
+            else:
+                arrs = device_arrays(di)
+        return di, arrs, time.perf_counter() - t0
 
     def _install_ready(self, block: bool) -> None:
         fut = self._inflight
         if fut is None or (not block and not fut.done()):
             return
-        self._inflight = None
-        try:
-            di, arrs = fut.result()
-        except Exception:
-            # failed build: old mirror stays live, pending replays, frozen
-            # overlay folds back under live (DESIGN.md §12) — no lost writes
-            self.shard.abort_swap()
-            self.shard.refresh_overlay_arrays()
-            self.failed_swaps += 1
-            return
-        self.shard.finish_swap(di)
-        self.shard.arrs = arrs
-        self.shard.refresh_overlay_arrays()   # frozen retired: live-only pack
-        self.swaps += 1
+        with self._phase("install"):
+            self._inflight = None
+            try:
+                di, arrs, build_s = fut.result()
+            except Exception:
+                # failed build: old mirror stays live, pending replays,
+                # frozen overlay folds back under live (DESIGN.md §12) — no
+                # lost writes
+                self.shard.abort_swap()
+                self._refresh_pack()
+                self.failed_swaps += 1
+                return
+            self.phase_s["compact_build"] += build_s
+            self.shard.finish_swap(di)
+            self.shard.arrs = arrs
+            self._refresh_pack()   # frozen retired: live-only pack
+            self.swaps += 1
 
     def _begin_step(self) -> None:
         self._install_ready(block=False)
@@ -610,10 +646,10 @@ class IndexEngine(BaseIndexEngine):
         self._install_ready(block=True)
 
     def _after_writes(self) -> None:
-        # compact() already rebuilds the overlay device pack (for the now-
-        # empty overlay); refresh it only when this step did not compact
+        # a synchronous compaction already refreshed the overlay device pack
+        # (for the now-empty overlay); refresh it only when this step did not
         if not self._maybe_compact():
-            self.shard.refresh_overlay_arrays()
+            self._refresh_pack()
 
     # ------------------------------------------------------------- read path
     def _snap(self) -> dict:
@@ -643,5 +679,4 @@ class IndexEngine(BaseIndexEngine):
             "overlay_merges": self.shard.overlay_merges,
             "overlay_reseeds": self.shard.overlay_reseeds,
             "write_h2d_bytes": self.shard.write_h2d_bytes,
-            "write_host_s": self.shard.write_host_s,
         }

@@ -64,7 +64,7 @@ from ..core.device_index import (build_device_index, install_shard_slices,
                                  stack_device_indexes, stacked_pool_caps)
 from ..core.partition import RangePartition
 from .index_engine import (BaseIndexEngine, IndexRequest, IndexShard,
-                           compaction_executor)
+                           compaction_executor, phase_span)
 
 
 class ShardedIndexEngine(BaseIndexEngine):
@@ -155,7 +155,6 @@ class ShardedIndexEngine(BaseIndexEngine):
                           if overlay_merge else None)
         self._pack_struct: tuple | None = None
         self.write_h2d_bytes = 0
-        self.write_host_s = 0.0
         self.overlay_merges = 0
         self.overlay_reseeds = 0
         self.ov_arrs = None
@@ -219,18 +218,21 @@ class ShardedIndexEngine(BaseIndexEngine):
         slice shapes, and push the slices to device — all off the request
         path.  Only reads state the in-flight window freezes (the shard's
         host index and mirror); ``sdi`` is captured at submit so a concurrent
-        full re-stack is detected at install time."""
+        full re-stack is detected at install time.  Returns its own seconds
+        last: the install adds them on the request thread."""
         import jax
         import jax.numpy as jnp
-        sh = self.shards[s]
-        di = refresh_device_index(sh.idx, sh.di)
-        slices = pad_shard_slices(sdi, di)
-        dev = None
-        if slices is not None:
-            dev = {f: jax.device_put(jnp.asarray(v))
-                   for f, v in slices.items()
-                   if f not in ("meta", "last_leaf_min")}
-        return s, di, sdi, slices, dev
+        t0 = time.perf_counter()
+        with phase_span("compact_build"):
+            sh = self.shards[s]
+            di = refresh_device_index(sh.idx, sh.di)
+            slices = pad_shard_slices(sdi, di)
+            dev = None
+            if slices is not None:
+                dev = {f: jax.device_put(jnp.asarray(v))
+                       for f, v in slices.items()
+                       if f not in ("meta", "last_leaf_min")}
+        return s, di, sdi, slices, dev, time.perf_counter() - t0
 
     def _install_ready(self, block: bool) -> None:
         """Swap stage (DESIGN.md §11), run between request batches: install
@@ -242,6 +244,12 @@ class ShardedIndexEngine(BaseIndexEngine):
         RAISED rolls its shard back via ``abort_swap`` (old epoch stays live,
         pending log replays — no lost writes, DESIGN.md §12).  Finished
         split/merge builds install last (``_install_repart``)."""
+        if not self._inflight and self._repart_inflight is None:
+            return
+        with self._phase("install"):
+            self._install_finished(block)
+
+    def _install_finished(self, block: bool) -> None:
         touched = False
         if self._inflight:
             ready = []
@@ -257,7 +265,8 @@ class ShardedIndexEngine(BaseIndexEngine):
                         touched = True
             if ready:
                 changed, dev_slices, need_full = [], {}, False
-                for s, di, sdi_ref, slices, dev in ready:
+                for s, di, sdi_ref, slices, dev, build_s in ready:
+                    self.phase_s["compact_build"] += build_s
                     self.shards[s].finish_swap(di)
                     changed.append(s)
                     if (sdi_ref is self.sdi and slices is not None
@@ -620,13 +629,17 @@ class ShardedIndexEngine(BaseIndexEngine):
         if sig == self._pack_sig and self.ov_arrs is not None:
             self.pack_skips += 1
             return self.ov_arrs
-        t0 = time.perf_counter()
-        struct = tuple((s[0], s[2]) for s in sig)
-        if (self._ov_merge is not None and self.ov_arrs is not None
-                and struct == self._pack_struct):
-            out = self._delta_merge_pack(sig, t0)
-            if out is not None:
-                return out
+        with self._phase("write_host"):
+            struct = tuple((s[0], s[2]) for s in sig)
+            if (self._ov_merge is not None and self.ov_arrs is not None
+                    and struct == self._pack_struct):
+                out = self._delta_merge_pack(sig)
+                if out is not None:
+                    return out
+            return self._rebuild_overlay_pack(sig, struct)
+
+    def _rebuild_overlay_pack(self, sig: tuple, struct: tuple) -> dict:
+        """The full O(total) rebuild of :meth:`_merged_overlay_pack`."""
         import jax.numpy as jnp
         from ..core.lookup import new_snap_token
         segs = []
@@ -670,10 +683,9 @@ class ShardedIndexEngine(BaseIndexEngine):
             # the replicated sharding instead of re-broadcasting per dispatch
             from ..parallel.index_placement import place_overlay_pack
             ovr = place_overlay_pack(ovr, self.mesh)
-        self.write_host_s += time.perf_counter() - t0
         return ovr
 
-    def _delta_merge_pack(self, sig: tuple, t0: float) -> dict | None:
+    def _delta_merge_pack(self, sig: tuple) -> dict | None:
         """O(batch) write-path sync: drain every shard's pending writes, ship
         the one concatenated sorted batch, merge on device.  Returns None
         when there is nothing to merge (a version bump without pending
@@ -697,7 +709,6 @@ class ShardedIndexEngine(BaseIndexEngine):
         self._pack_live = bound
         self.write_h2d_bytes += nbytes
         self.overlay_merges += 1
-        self.write_host_s += time.perf_counter() - t0
         return ovr
 
     # ------------------------------------------------------------- read path
@@ -816,7 +827,6 @@ class ShardedIndexEngine(BaseIndexEngine):
             "overlay_merges": self.overlay_merges,
             "overlay_reseeds": self.overlay_reseeds,
             "write_h2d_bytes": self.write_h2d_bytes,
-            "write_host_s": self.write_host_s,
             "splits": self.splits,
             "merges": self.merges,
             "repart_failures": self.repart_failures,

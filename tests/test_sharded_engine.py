@@ -12,7 +12,7 @@ import pytest
 from repro.core import Aulid, AulidConfig, BlockDevice, partition_bulkload
 from repro.core.workloads import make_dataset, payloads_for
 from repro.serving import IndexEngine, ShardedIndexEngine
-from repro.serving.index_engine import pad_queries, scan_bucket
+from repro.serving.index_engine import PHASES, pad_queries, scan_bucket
 
 SMALL_GEOM = dict(leaf_capacity=16, pa_classes=(4, 8), bt_child_capacity=15)
 
@@ -151,3 +151,93 @@ class TestScanBucketing:
         q = pad_queries([1, 2, 3])
         assert q.shape == (4,) and q[3] == np.uint64(0xFFFFFFFFFFFFFFFF)
         assert pad_queries([1]).shape == (1,)
+
+
+# the phases a request-path step is made of (``compact_build`` runs on the
+# pool thread; ``install`` nests the pack rebuild after a swap)
+STEP_PHASES = ("install", "write_apply", "write_host", "read_dispatch",
+               "read_wait", "read_unpack")
+
+
+def _phase_delta(eng, drive) -> dict:
+    s0 = eng.stats()
+    drive()
+    s1 = eng.stats()
+    return {p: s1[f"{p}_s"] - s0[f"{p}_s"] for p in PHASES}
+
+
+def _mixed_requests(eng, keys) -> None:
+    for k in keys[:24:3]:
+        eng.get(int(k))
+    eng.insert(int(keys[5]) + 1, 9)
+    eng.delete(int(keys[7]))
+    eng.scan(int(keys[11]), 12)
+
+
+class TestPhaseAccounting:
+    @pytest.mark.parametrize("which", ["mono", "sharded"])
+    def test_step_phases_add_up_within_the_step(self, which):
+        """Every phase that ran in a step with writes, gets and scans counts
+        above 0, and the request-path phases fit inside the step's own."""
+        keys, mono, shrd = mk_engines(gamma=10.0)       # no compaction
+        eng = mono if which == "mono" else shrd
+        _mixed_requests(eng, keys)
+        d = _phase_delta(eng, eng.step)
+        for p in ("write_apply", "write_host", "read_dispatch", "read_wait",
+                  "read_unpack"):
+            assert d[p] > 0, p
+        assert d["install"] == 0 and d["compact_build"] == 0
+        assert sum(d[p] for p in STEP_PHASES) <= eng.step_seconds[-1]
+        assert "step_s" not in eng.stats()
+        for gone in ("throughput_ops_s", "p99_step_s", "mean_read_batch"):
+            assert gone not in eng.stats()
+
+    @pytest.mark.parametrize("which", ["mono", "sharded"])
+    def test_background_compaction_times_build_and_install(self, which):
+        """A forced background compaction raises ``compact_build_s`` (timed
+        on the pool thread, added at install) and ``install_s``."""
+        keys, mono, shrd = mk_engines(gamma=0.02, async_compact=True)
+        eng = mono if which == "mono" else shrd
+        rng = np.random.default_rng(5)
+        for k in rng.integers(int(keys[0]), int(keys[-1]), 200):
+            eng.insert(int(k), 1)
+        eng.step()
+        assert eng.stats()["inflight"] >= 1
+        steps = eng.steps
+        d = _phase_delta(eng, eng.drain_compactions)
+        assert eng.stats()["swaps"] >= 1
+        assert d["compact_build"] > 0 and d["install"] > 0
+        assert eng.steps == steps
+
+    @pytest.mark.parametrize("which", ["mono", "sharded"])
+    def test_phases_are_profiler_spans_inside_the_step(self, tmp_path,
+                                                       which):
+        """With a profiler trace running, each phase is an ``aulid.*`` span
+        on the host plane, nested inside the caller's span around
+        ``step()``: one span per phase entered, not one per request."""
+        import jax
+        from jax.profiler import ProfileData
+        keys, mono, shrd = mk_engines(gamma=10.0)
+        eng = mono if which == "mono" else shrd
+        _mixed_requests(eng, keys)
+        eng.step()                          # compile outside the trace
+        _mixed_requests(eng, keys)
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        with jax.profiler.TraceAnnotation("caller.step"):
+            eng.step()
+        jax.profiler.stop_trace()
+        xplane = sorted(tmp_path.rglob("*.xplane.pb"))[-1]
+        spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                 for plane in ProfileData.from_file(str(xplane)).planes
+                 if plane.name.startswith("/host:")
+                 for line in plane.lines for e in line.events
+                 if e.name == "caller.step" or e.name.startswith("aulid.")]
+        (_, lo, hi), = [s for s in spans if s[0] == "caller.step"]
+        names = sorted(n for n, _, _ in spans if n != "caller.step")
+        # the read phases twice: the get batch and the one scan bucket
+        want = (["write_apply", "write_host"]
+                + ["read_dispatch", "read_wait", "read_unpack"] * 2)
+        assert names == sorted(f"aulid.{p}" for p in want)
+        assert all(lo <= a <= b <= hi for _, a, b in spans)
