@@ -138,6 +138,7 @@ class IndexShard:
     ov_struct: Optional[tuple] = None
     write_h2d_bytes: int = 0
     overlay_merges: int = 0
+    overlay_merge_slots: int = 0
     overlay_reseeds: int = 0
 
     @classmethod
@@ -271,11 +272,13 @@ class IndexShard:
         Delta path (steady state): the device pack is the source of truth
         between compactions — drain the live overlay's pending writes, ship
         only that sorted batch (O(batch) H2D), and fold it in on device via
-        the bound overlay-merge backend.  The path is valid exactly while
-        the (live uid, frozen uid) structure beneath the pack is unchanged:
-        a freeze merely relabels content the pack already merges (the
-        frozen∪live view is invariant under the relabeling), and batch
-        writes stay newest, so last-writer-wins keeps the pack exact.
+        the bound overlay-merge backend, over the pack's live prefix
+        (``merge_window`` of the fill bound ``overlay_live()``).  The path
+        is valid exactly while the (live uid, frozen uid) structure beneath
+        the pack is unchanged: a freeze merely relabels content the pack
+        already merges (the frozen∪live view is invariant under the
+        relabeling), and batch writes stay newest, so last-writer-wins
+        keeps the pack exact.
 
         Reseed path (ownership handoff back to the host dicts): any uid
         change — freeze, finish_swap, abort_swap, or a clear() (which takes
@@ -288,12 +291,12 @@ class IndexShard:
             from ..core.lookup import merge_overlay_pack
             batch = self.overlay.take_batch()
             if batch[0].size:
-                cap_out = max(int(self.ov_arrs["ov_pack"].shape[1]),
-                              next_pow2(self.overlay_live()))
-                self.ov_arrs, nbytes = merge_overlay_pack(
-                    self.ov_arrs, batch, cap_out, merge_fn=self.ov_merge_fn)
+                self.ov_arrs, nbytes, slots = merge_overlay_pack(
+                    self.ov_arrs, batch, self.overlay_live(),
+                    merge_fn=self.ov_merge_fn)
                 self.write_h2d_bytes += nbytes
                 self.overlay_merges += 1
+                self.overlay_merge_slots += slots
             return
         from ..core.lookup import overlay_arrays, overlay_arrays_merged
         self.overlay.mark_synced()
@@ -677,6 +680,7 @@ class IndexEngine(BaseIndexEngine):
             "mirror_refreshes": self.di.refreshes,
             "mirror_full_builds": self.di.full_builds,
             "overlay_merges": self.shard.overlay_merges,
+            "overlay_merge_slots": self.shard.overlay_merge_slots,
             "overlay_reseeds": self.shard.overlay_reseeds,
             "write_h2d_bytes": self.shard.write_h2d_bytes,
         }

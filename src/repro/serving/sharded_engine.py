@@ -156,6 +156,7 @@ class ShardedIndexEngine(BaseIndexEngine):
         self._pack_struct: tuple | None = None
         self.write_h2d_bytes = 0
         self.overlay_merges = 0
+        self.overlay_merge_slots = 0   # sum of the delta merges' windows
         self.overlay_reseeds = 0
         self.ov_arrs = None
         self.ov_arrs = self._merged_overlay_pack()
@@ -687,10 +688,10 @@ class ShardedIndexEngine(BaseIndexEngine):
 
     def _delta_merge_pack(self, sig: tuple) -> dict | None:
         """O(batch) write-path sync: drain every shard's pending writes, ship
-        the one concatenated sorted batch, merge on device.  Returns None
-        when there is nothing to merge (a version bump without pending
-        writes — e.g. an external ``arrays()`` drain), falling back to the
-        full rebuild."""
+        the one concatenated sorted batch, merge it on device into the
+        pack's live prefix (``merge_window``).  Returns None when there is
+        nothing to merge (a version bump without pending writes — e.g. an
+        external ``arrays()`` drain), falling back to the full rebuild."""
         from ..core.lookup import merge_overlay_pack
         batches = [sh.overlay.take_batch() for sh in self.shards]
         bk = np.concatenate([b[0] for b in batches])
@@ -699,16 +700,16 @@ class ShardedIndexEngine(BaseIndexEngine):
         bp = np.concatenate([b[1] for b in batches])
         bt = np.concatenate([b[2] for b in batches])
         # upper bound on merged pack fill (scan ov_bound); exact counts live
-        # in the host dicts, so cap growth is known without a device sync
+        # in the host dicts, so the merge window and any cap growth are
+        # known without a device sync
         bound = sum(sh.overlay_live() for sh in self.shards)
-        cap_out = max(int(self.ov_arrs["ov_pack"].shape[1]),
-                      self._ov_floor, next_pow2(bound))
-        ovr, nbytes = merge_overlay_pack(self.ov_arrs, (bk, bp, bt), cap_out,
-                                         merge_fn=self._ov_merge)
+        ovr, nbytes, slots = merge_overlay_pack(
+            self.ov_arrs, (bk, bp, bt), bound, merge_fn=self._ov_merge)
         self._pack_sig = sig
         self._pack_live = bound
         self.write_h2d_bytes += nbytes
         self.overlay_merges += 1
+        self.overlay_merge_slots += slots
         return ovr
 
     # ------------------------------------------------------------- read path
@@ -825,6 +826,7 @@ class ShardedIndexEngine(BaseIndexEngine):
             "inflight": len(self._inflight),
             "pack_skips": self.pack_skips,
             "overlay_merges": self.overlay_merges,
+            "overlay_merge_slots": self.overlay_merge_slots,
             "overlay_reseeds": self.overlay_reseeds,
             "write_h2d_bytes": self.write_h2d_bytes,
             "splits": self.splits,

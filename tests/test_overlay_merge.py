@@ -23,7 +23,8 @@ from test_async_compaction import ManualExecutor
 from repro.core import (Aulid, AulidConfig, BlockDevice, DeltaOverlay,
                         partition_bulkload)
 from repro.core.delta_overlay import next_pow2
-from repro.core.lookup import (empty_overlay_pack, merge_overlay_pack_jnp,
+from repro.core.lookup import (empty_overlay_pack, merge_overlay_pack,
+                               merge_overlay_pack_jnp, merge_window,
                                overlay_merge_backend_fn)
 from repro.core.workloads import make_dataset, payloads_for
 from repro.kernels.overlay_merge import (overlay_merge_pack,
@@ -140,6 +141,68 @@ class TestMergeParity:
         da = {k: (p, t) for k, p, t in a}
         db = {k: (p, t) for k, p, t in b}
         assert_all_merge_paths(da, db)
+
+
+# the windowed merge's pack: capacity well above the windows it merges into
+WIN_CAP = 128
+
+
+def _keys_of(base, n, step=2):
+    return {base + step * i: (base + i, i % 4 == 0) for i in range(n)}
+
+
+# (pack dict, batch dict, fill bound the caller passes, window it yields)
+WINDOW_CASES = {
+    # 10 live + 6 (2 colliding): bound 16, far below the capacity
+    "well_below": (_keys_of(100, 10), {**_keys_of(101, 4),
+                   100: (7, True), 104: (9, False)}, 16, 16),
+    # 24 live + 8 new keys: the merged fill is exactly the window
+    "edge": (_keys_of(0, 24), _keys_of(1001, 8), 32, 32),
+    # every batch key collides, tombstones flip both ways
+    "all_collide": (_keys_of(0, 20),
+                    {2 * i: (500 + i, i % 3 == 0) for i in range(20)},
+                    40, 64),
+    # 30 live (window bucket 32) pushed to 40 by the batch: next bucket
+    "bucket_up": (_keys_of(0, 30), _keys_of(2001, 10), 40, 64),
+    # the bound reaches the capacity: the whole-pack merge
+    "at_capacity": (_keys_of(0, 90), _keys_of(3001, 16), 106, WIN_CAP),
+    # the bound passes the capacity: the pack grows, the whole-pack merge
+    "growth": (_keys_of(0, 120), _keys_of(4001, 20), 140, 2 * WIN_CAP),
+}
+
+
+class TestWindowedMerge:
+    """The delta merge over the live prefix ``[0, w)`` of the pack is bit
+    for bit today's whole-capacity merge, and leaves ``[w, cap)`` padding."""
+
+    @pytest.mark.parametrize("backend", ["jnp", "fused_interpret"])
+    @pytest.mark.parametrize("case", sorted(WINDOW_CASES))
+    def test_window_equals_full_merge(self, backend, case):
+        a, b, bound, want_w = WINDOW_CASES[case]
+        fn = overlay_merge_backend_fn(backend)
+        pack = dict_pack(a, WIN_CAP)
+        cap_out = max(WIN_CAP, next_pow2(bound))
+        full = np.asarray(fn(jnp.asarray(pack),
+                             jnp.asarray(dict_pack(b, next_pow2(len(b)))),
+                             cap_out))
+        np.testing.assert_array_equal(full, dict_pack({**a, **b}, cap_out))
+        batch = tuple(np.asarray(x) for x in dict_pack(b, len(b)))
+        ovr, nbytes, w = merge_overlay_pack(
+            {"ov_pack": jnp.asarray(pack)}, (batch[0], batch[1],
+                                             batch[2].astype(bool)),
+            bound, merge_fn=fn)
+        got = np.asarray(ovr["ov_pack"])
+        assert w == want_w and nbytes == 3 * 8 * next_pow2(max(len(b), 8))
+        np.testing.assert_array_equal(got, full)
+        assert (got[0, w:] == UM).all() and not got[1:, w:].any()
+        assert (got[0, :w] != UM).sum() == len({**a, **b})
+
+    def test_merge_window_buckets(self):
+        assert merge_window(0, 8, 1 << 24) == 8
+        assert merge_window(5_000, 8192, 1 << 24) == 8192
+        assert merge_window(40_000, 8192, 1 << 24) == 65536
+        assert merge_window(65536, 8192, 1 << 24) == 65536
+        assert merge_window(20_000_000, 8192, 1 << 24) == 1 << 24
 
 
 class TestDeltaOverlayBatching:
@@ -409,6 +472,45 @@ class TestShardedWritePath:
             assert a.result == b.result, a.key
         assert eng.stats()["num_shards"] > 3
         assert eng.stats()["overlay_merges"] > 0
+
+    @pytest.mark.parametrize("engine", ["sharded", "monolithic"])
+    def test_merge_window_below_capacity(self, engine):
+        """Write steps far below the compaction threshold: every answer
+        matches the dict oracle, every step merges on device, and the mean
+        merge window stays below the pack's capacity."""
+        keys = make_dataset("covid", 1_200, seed=1)
+        if engine == "sharded":
+            part = partition_bulkload(keys, payloads_for(keys), 3,
+                                      cfg=AulidConfig(**SMALL_GEOM))
+            eng = ShardedIndexEngine(part, gamma=0.5, backend="jnp",
+                                     overlay_merge=True)
+        else:
+            eng = IndexEngine(small_build(keys), gamma=0.5, backend="jnp",
+                              overlay_merge=True)
+        oracle = {int(k): int(k) + 1 for k in keys}
+        rng = np.random.default_rng(11)
+        for step in range(8):
+            reqs = []
+            for i in range(12):
+                k = (int(rng.integers(0, 2**50)) if rng.random() < 0.6
+                     else int(rng.choice(keys)))
+                eng.insert(k, step * 100 + i)
+                oracle[k] = step * 100 + i
+            for _ in range(3):
+                k = int(rng.choice(keys))
+                eng.delete(k)
+                oracle.pop(k, None)
+            for _ in range(14):
+                k = (int(rng.choice(keys)) if rng.random() < 0.7
+                     else int(rng.integers(0, 2**50)))
+                reqs.append((k, eng.get(k)))
+            eng.step()
+            for k, r in reqs:
+                assert r.done and r.result == oracle.get(k), (step, k)
+        s = eng.stats()
+        cap = int(eng.ov_arrs["ov_pack"].shape[1])
+        assert s["compactions"] == 0 and s["overlay_merges"] == 8
+        assert s["overlay_merge_slots"] / s["overlay_merges"] < cap
 
     def test_backend_fn_resolution(self):
         fn = overlay_merge_backend_fn("jnp")

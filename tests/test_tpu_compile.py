@@ -10,7 +10,7 @@ Covered: the jitted jnp read and scan steps of the sharded engine at the
 pool widths of the 200M-key, 16-shard deployment ``chip_smoke.py`` loads,
 on one chip and as the mesh engine over four; the fused lookup kernel with
 both leaf strategies at a geometry the tuner admits; the overlay-merge
-kernel.
+kernel; the jnp overlay merge over a window of the 200M-key pack.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and the test runner's
@@ -27,7 +27,9 @@ from jax.sharding import SingleDeviceSharding
 from repro.core import Aulid, DeltaOverlay, partition_bulkload
 from repro.core.device_index import build_device_index, stack_device_indexes
 from repro.core.lookup import (device_arrays, lookup_batch_sharded_overlay,
-                               overlay_arrays, scan_batch_sharded_overlay,
+                               merge_overlay_pack_jnp, overlay_arrays,
+                               overlay_merge_write_back,
+                               scan_batch_sharded_overlay,
                                stacked_device_arrays)
 from repro.core.workloads import make_dataset, payloads_for
 from repro.kernels.fused_lookup import ops as fops
@@ -200,3 +202,16 @@ def test_merge_kernel(one_chip, S, ca, cb):
             a, a, a, a, a, b, b, b, b, b, cap_out=ca,
             interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("w", [16384, 1 << 20])
+def test_merge_window_200m(one_chip, w):
+    """The jnp delta merge over a window of the 200M-key deployment's pack,
+    and its write-back into the whole (3, 2^24) pack."""
+    pack = _sds((3, OVERLAY_CAP_200M), jnp.uint64, one_chip)
+    head = _sds((3, w), jnp.uint64, one_chip)
+    batch = _sds((3, 8192), jnp.uint64, one_chip)
+    merged = merge_overlay_pack_jnp.lower(head, batch, cap_out=w).compile()
+    assert merged.out_info.shape == (3, w)
+    back = overlay_merge_write_back.lower(pack, head).compile()
+    assert back.out_info.shape == (3, OVERLAY_CAP_200M)
