@@ -10,17 +10,21 @@ Request flow per :meth:`step`:
 1. drain the queue, partitioning into writes and reads (step-level
    consistency: every write queued before the step is visible to every read
    executed in it — the oracle the property tests assert against);
-2. apply writes to the host ``Aulid`` (which journals them) *and* to the
-   ``DeltaOverlay`` — the device mirror itself is untouched;
-3. compaction policy: once ``len(overlay) >= gamma * n`` the overlay is
-   folded into a fresh snapshot via ``refresh_device_index`` (the journal
-   fast path re-mirrors only touched leaf rows when no SMO happened) and
-   cleared — mirroring AULID's own Adjust criterion of amortizing structural
-   work against a fraction of covered data (paper §4.4);
-4. execute all point reads as ONE fused ``lookup_batch_overlay`` device batch
+2. record writes in the ``DeltaOverlay`` (results computed overlay-first)
+   and in the shard's host log, then dispatch the overlay pack's device
+   merge — the device mirror itself is untouched;
+3. execute all point reads as ONE fused ``lookup_batch_overlay`` device batch
    and scans as one ``scan_batch_overlay`` batch per power-of-two scan-length
    bucket (mixed scan lengths share compiles; results slice to the requested
-   count).
+   count); behind the first read dispatch, while the device merges and
+   reads, the host log is applied to the host ``Aulid`` (which journals the
+   writes) — no read consults the host index (DESIGN.md §3);
+4. compaction policy, with every unfrozen host log empty: once
+   ``len(overlay) >= gamma * n`` the overlay is folded into a fresh snapshot
+   via ``refresh_device_index`` (the journal fast path re-mirrors only
+   touched leaf rows when no SMO happened) and cleared — mirroring AULID's
+   own Adjust criterion of amortizing structural work against a fraction of
+   covered data (paper §4.4).
 
 Write semantics are unique-key upserts (``insert`` overwrites an existing
 key's payload; ``delete`` removes the key) so host, overlay, and device views
@@ -50,12 +54,14 @@ MIN_SCAN_BUCKET = 8
 
 # phases of the engine step, each timed into ``phase_s[name]`` (reported by
 # ``stats()`` as ``<name>_s``) and traced as the profiler span
-# ``aulid.<name>``: the swap install on the request path, the per-write host
-# loop, the overlay-pack refresh, the read batches' dispatch / wait on the
-# device / per-request unpacking, and the background compaction build (timed
-# on its pool thread, added at install).  The whole step is ``step_seconds``.
+# ``aulid.<name>``: the swap install on the request path, the per-write loop
+# (routing, overlay record, results), the overlay-pack refresh, the read
+# batches' dispatch / wait on the device / per-request unpacking, the host
+# index update of the step's writes (run behind the first device dispatch),
+# and the background compaction build (timed on its pool thread, added at
+# install).  The whole step is ``step_seconds``.
 PHASES = ("install", "write_apply", "write_host", "read_dispatch",
-          "read_wait", "read_unpack", "compact_build")
+          "read_wait", "read_unpack", "write_index", "compact_build")
 
 
 def phase_span(name: str):
@@ -118,10 +124,12 @@ class IndexShard:
     pools instead (``with_arrays=False``), so a shard compaction only touches
     its own slice of the stack.
 
-    ``frozen_overlay``/``pending`` are the double-buffered compaction state
-    (DESIGN.md §11): while a background build is in flight the pre-freeze
-    overlay stays merged into reads, the host index is read-only, and writes
-    land in the (fresh) live overlay plus a pending log replayed at swap."""
+    ``pending`` is the host log: writes recorded in the overlay whose host
+    index update has not run yet (DESIGN.md §3).  The engine applies it
+    behind each step's device work (``apply_log``); while a background build
+    is in flight (``frozen_overlay``, DESIGN.md §11) the pre-freeze overlay
+    stays merged into reads, the host index is read-only, and the log waits
+    for the swap.  A shard freezes or compacts only with an empty log."""
     idx: Aulid
     overlay: DeltaOverlay
     di: DeviceIndex
@@ -156,30 +164,44 @@ class IndexShard:
         return sh
 
     # ---------------------------------------------------------------- writes
-    def apply_write(self, op: str, key: int, payload: int = 0):
-        """Host + overlay write (unique-key upsert semantics, module
-        docstring).  Returns the request result (True / delete outcome).
-
-        While a background compaction is in flight the host index is
-        read-only (the build thread is walking it), so writes defer: they
-        land in the live overlay immediately (reads see them this step) and
-        in the pending log replayed at ``finish_swap``.  Results are computed
-        overlay-first so they match the synchronous path exactly."""
-        if self.frozen_overlay is not None:
-            self.pending.append((op, key, payload))
-            if op == "insert":
-                self.overlay.record_insert(key, payload)
-                return True
-            existed = self._key_live(key)
-            self.overlay.record_delete(key)
-            return existed
+    def record_write(self, op: str, key: int, payload: int = 0):
+        """Record a write (unique-key upsert semantics, module docstring) in
+        the live overlay, which reads see this step, and in the host log.
+        Returns the request result (True / delete outcome), computed
+        overlay-first: every logged write is also in the live overlay, so
+        the served view answers without the host index's update."""
+        self.pending.append((op, key, payload))
         if op == "insert":
-            if not self.idx.update(key, payload):
-                self.idx.insert(key, payload)
             self.overlay.record_insert(key, payload)
             return True
+        existed = self._key_live(key)
         self.overlay.record_delete(key)
-        return self.idx.delete(key)
+        return existed
+
+    def apply_write(self, op: str, key: int, payload: int = 0):
+        """Record a write and apply it to the host index at once, unless a
+        background compaction is in flight (``apply_log``)."""
+        result = self.record_write(op, key, payload)
+        self.apply_log()
+        return result
+
+    def apply_log(self) -> int:
+        """Apply the host log to the host index in arrival order and empty
+        it; returns the number of writes applied.  Applied writes journal
+        and fold at the next compaction; the overlay already serves them,
+        so the served view never moves.  While a background compaction is
+        in flight the host index is read-only (the build thread is walking
+        it): the log then waits for ``finish_swap`` / ``abort_swap``."""
+        if self.frozen_overlay is not None:
+            return 0
+        log, self.pending = self.pending, []
+        for op, key, payload in log:
+            if op == "insert":
+                if not self.idx.update(key, payload):
+                    self.idx.insert(key, payload)
+            else:
+                self.idx.delete(key)
+        return len(log)
 
     def _key_live(self, key: int) -> bool:
         """Whether ``key`` currently exists in the served view — the deferred
@@ -205,6 +227,7 @@ class IndexShard:
         Repartition builds reuse the same freeze window but are counted by
         the engine's split/merge counters instead (``count=False``)."""
         assert self.frozen_overlay is None, "compaction already in flight"
+        assert not self.pending, "freeze with unapplied host writes"
         self.frozen_overlay = self.overlay
         self.overlay = self.frozen_overlay.spawn_empty()
         if count:
@@ -213,18 +236,10 @@ class IndexShard:
 
     def finish_swap(self, new_di: DeviceIndex) -> None:
         """Retire the frozen overlay and replay the pending log into the
-        host index (the writes deferred while the build ran).  Replayed
-        writes re-journal and fold at the NEXT compaction; the live overlay
-        already serves them to reads, so the served view never moves."""
+        host index (the writes deferred while the build ran)."""
         self.di = new_di
         self.frozen_overlay = None
-        pending, self.pending = self.pending, []
-        for op, key, payload in pending:
-            if op == "insert":
-                if not self.idx.update(key, payload):
-                    self.idx.insert(key, payload)
-            else:
-                self.idx.delete(key)
+        self.apply_log()
 
     def abort_swap(self) -> None:
         """Roll back a freeze whose background build FAILED (DESIGN.md §12):
@@ -236,13 +251,7 @@ class IndexShard:
         assert self.frozen_overlay is not None, "no build in flight"
         frozen, self.frozen_overlay = self.frozen_overlay, None
         self.overlay.merge_under(frozen)
-        pending, self.pending = self.pending, []
-        for op, key, payload in pending:
-            if op == "insert":
-                if not self.idx.update(key, payload):
-                    self.idx.insert(key, payload)
-            else:
-                self.idx.delete(key)
+        self.apply_log()
 
     def compact(self) -> None:
         """Fold the overlay into a fresh snapshot and clear it (DESIGN.md §3).
@@ -254,6 +263,7 @@ class IndexShard:
         refresh of the (now empty) overlay pack."""
         assert self.frozen_overlay is None, \
             "sync compact during in-flight compaction (drain first)"
+        assert not self.pending, "compact with unapplied host writes"
         old = self.di
         self.di = refresh_device_index(self.idx, old)
         if self.arrs is not None:
@@ -325,7 +335,8 @@ class BaseIndexEngine:
 
     Subclasses bind the jitted read entry points (``self._lookup`` /
     ``self._scan``, called with the device operands `_snap()` / `_ov()`) and
-    implement the write/compaction path (`_apply_write`, `_after_writes`)."""
+    implement the write/compaction path (`_apply_write`, `_after_writes`,
+    `_apply_host_logs`, `_maybe_compact`)."""
 
     def __init__(self):
         self.queue: list[IndexRequest] = []
@@ -334,6 +345,10 @@ class BaseIndexEngine:
         self.steps = 0
         self.reads_served = 0
         self.writes_applied = 0
+        # writes whose host-index update ran behind a device dispatch of
+        # their own step (``_drain_host_logs``)
+        self.writes_deferred = 0
+        self._logs_due = False
         self.step_seconds: list[float] = []   # per-step latency (p99 source)
         self.phase_s = dict.fromkeys(PHASES, 0.0)
         # first-seen read specializations — static args (count bucket /
@@ -418,11 +433,33 @@ class BaseIndexEngine:
         raise NotImplementedError
 
     def _apply_write(self, req: IndexRequest) -> None:
+        """Route one write, record it in its shard's overlay and host log,
+        and set its result (``IndexShard.record_write``)."""
         raise NotImplementedError
 
     def _after_writes(self) -> None:
-        """Compaction policy + overlay device-pack refresh."""
+        """Overlay device-pack refresh: dispatches the step's merge."""
         raise NotImplementedError
+
+    def _apply_host_logs(self) -> int:
+        """Apply every unfrozen shard's host log; returns writes applied."""
+        raise NotImplementedError
+
+    def _maybe_compact(self) -> None:
+        """Compaction policy, run with every unfrozen host log empty."""
+        raise NotImplementedError
+
+    def _drain_host_logs(self) -> None:
+        """Apply the step's host logs to the host indexes (phase
+        ``write_index``), once per step: the read paths call it right after
+        the step's first device dispatch, so the host work runs while the
+        device merges and reads; ``step()`` calls it after the reads for a
+        step without any."""
+        if not self._logs_due:
+            return
+        self._logs_due = False
+        with self._phase("write_index"):
+            self.writes_deferred += self._apply_host_logs()
 
     # ------------------------------------------------------------- read path
     def _serve_gets(self, gets: list[IndexRequest]) -> None:
@@ -432,6 +469,7 @@ class BaseIndexEngine:
             self._note_read_shape("get", q.shape[0])
             pay, found, _ = self._lookup(self._snap(), self._ov(), q,
                                          height=self._height())
+        self._drain_host_logs()
         with self._phase("read_wait"):
             pay = np.asarray(pay)
             found = np.asarray(found)
@@ -457,6 +495,7 @@ class BaseIndexEngine:
                                            count=bucket,
                                            height=self._height(),
                                            ov_bound=ov_bound)
+            self._drain_host_logs()
             with self._phase("read_wait"):
                 ks, ps, valid = map(np.asarray, (ks, ps, valid))
             with self._phase("read_unpack"):
@@ -469,8 +508,10 @@ class BaseIndexEngine:
 
     # ------------------------------------------------------------------ step
     def step(self) -> int:
-        """Drain the queue: writes (host + overlay), compaction policy, then
-        all reads as fused device batches. Returns requests completed."""
+        """Drain the queue: record the writes (overlay + host log) and
+        dispatch the pack merge, serve all reads as fused device batches
+        with the host-log drain behind the first dispatch, then the
+        compaction policy (DESIGN.md §3). Returns requests completed."""
         if not self.queue:
             return 0
         t0 = time.perf_counter()
@@ -479,6 +520,7 @@ class BaseIndexEngine:
         writes = [r for r in batch if r.op in ("insert", "delete")]
         gets = [r for r in batch if r.op == "get"]
         scans = [r for r in batch if r.op == "scan"]
+        self._logs_due = bool(writes)
         if writes:
             with self._phase("write_apply"):
                 for r in writes:
@@ -488,6 +530,12 @@ class BaseIndexEngine:
             self._serve_gets(gets)
         if scans:
             self._serve_scans(scans)
+        if writes:
+            self._drain_host_logs()
+            # after the drain and the step's reads: a freeze hands the build
+            # a host index holding every write its overlay retires, and no
+            # read of the step shares it with a compaction's device update
+            self._maybe_compact()
         self._end_step()
         self.steps += 1
         self.step_seconds.append(time.perf_counter() - t0)
@@ -505,6 +553,7 @@ class BaseIndexEngine:
             "steps": self.steps,
             "reads_served": self.reads_served,
             "writes_applied": self.writes_applied,
+            "writes_deferred": self.writes_deferred,
             "read_shape_misses": self.read_shape_misses,
             **{f"{k}_s": v for k, v in self.phase_s.items()},
         }
@@ -576,9 +625,12 @@ class IndexEngine(BaseIndexEngine):
 
     # ------------------------------------------------------------ write path
     def _apply_write(self, req: IndexRequest) -> None:
-        req.result = self.shard.apply_write(req.op, req.key, req.payload)
+        req.result = self.shard.record_write(req.op, req.key, req.payload)
         req.done = True
         self.writes_applied += 1
+
+    def _apply_host_logs(self) -> int:
+        return self.shard.apply_log()
 
     def compact(self) -> None:
         self.drain_compactions()
@@ -589,17 +641,17 @@ class IndexEngine(BaseIndexEngine):
         with self._phase("write_host"):
             self.shard.refresh_overlay_arrays()
 
-    def _maybe_compact(self) -> bool:
+    def _maybe_compact(self) -> None:
         if not (self.auto_compact and self.shard.needs_compaction(self.gamma)):
-            return False
+            return
         if not self.async_compact:
             self.shard.compact()
-            self._refresh_pack()
-            return True
-        if self._inflight is None:     # one build in flight per engine
+        elif self._inflight is None:     # one build in flight per engine
             self.shard.freeze()
             self._inflight = compaction_executor().submit(self._build_job)
-        return False   # reads still need the merged frozen+live pack
+        else:
+            return
+        self._refresh_pack()   # empty, or the merged frozen+live pack
 
     def _build_job(self):
         """Background build+upload (DESIGN.md §11): refresh the host mirror
@@ -649,10 +701,7 @@ class IndexEngine(BaseIndexEngine):
         self._install_ready(block=True)
 
     def _after_writes(self) -> None:
-        # a synchronous compaction already refreshed the overlay device pack
-        # (for the now-empty overlay); refresh it only when this step did not
-        if not self._maybe_compact():
-            self._refresh_pack()
+        self._refresh_pack()
 
     # ------------------------------------------------------------- read path
     def _snap(self) -> dict:

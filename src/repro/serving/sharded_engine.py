@@ -6,8 +6,9 @@ stalls the whole key space behind an O(n) mirror rebuild.  This engine
 partitions the key space into range shards (``core/partition.py``) and keeps
 one :class:`IndexShard` per range:
 
-* **writes** route to their shard's host index + overlay with one
-  ``searchsorted`` over the boundary table;
+* **writes** route to their shard's overlay + host log with one
+  ``searchsorted`` over the boundary table; the logs reach the host indexes
+  behind the step's first device dispatch (DESIGN.md §3);
 * **compaction** is *shard-local*: a hot shard folding its overlay refreshes
   only its own mirror and re-uploads only its own slice of the stacked pools
   (``restack_shard`` + ``update_stacked_shard``) — cold shards' mirrors keep
@@ -176,24 +177,28 @@ class ShardedIndexEngine(BaseIndexEngine):
     def _apply_write(self, req: IndexRequest) -> None:
         s = self.part.shard_of(req.key)
         sh = self.shards[s]
-        req.result = sh.apply_write(req.op, req.key, req.payload)
+        req.result = sh.record_write(req.op, req.key, req.payload)
         req.done = True
         self.writes_applied += 1
         if req.op == "insert":
             self._write_counts[s] += 1   # load-monitor insert-rate window
 
     def _after_writes(self) -> None:
-        if self.auto_compact:
-            self._maybe_compact()
         self.ov_arrs = self._merged_overlay_pack()
+
+    def _apply_host_logs(self) -> int:
+        # a frozen shard's log waits for its swap (finish_swap, abort_swap,
+        # or _route_window_writes)
+        return sum(sh.apply_log() for sh in self.shards)
 
     def _maybe_compact(self) -> None:
         """Shard-local compaction: only shards past their own gamma threshold
         fold their overlay.  Synchronous mode re-uploads their mirror slices
         inline; double-buffered mode (default) freezes each shard's overlay
         and hands the build+upload to a background thread (DESIGN.md §11) —
-        one build in flight per shard."""
-        if self._repart_inflight is not None:
+        one build in flight per shard.  Either way the overlay pack then
+        reseeds (empty, or frozen merged under live)."""
+        if not self.auto_compact or self._repart_inflight is not None:
             # a repartition owns the maintenance window: shard ids shift at
             # its install, so no compaction may start (or restack) under it —
             # overlays keep absorbing writes and compact after the install
@@ -207,11 +212,12 @@ class ShardedIndexEngine(BaseIndexEngine):
             for s in changed:
                 self.shards[s].compact()
             self._refresh_stack(changed)
-            return
-        for s in changed:
-            self.shards[s].freeze()
-            self._inflight[s] = compaction_executor().submit(
-                self._build_job, s, self.sdi)
+        else:
+            for s in changed:
+                self.shards[s].freeze()
+                self._inflight[s] = compaction_executor().submit(
+                    self._build_job, s, self.sdi)
+        self.ov_arrs = self._merged_overlay_pack()
 
     def _build_job(self, s: int, sdi):
         """Background build+upload for shard ``s`` (freeze -> build -> upload
@@ -405,6 +411,7 @@ class ShardedIndexEngine(BaseIndexEngine):
             return False
         if self.shards[s].frozen_overlay is not None:
             return False
+        assert not self.shards[s].pending, "split with unapplied host writes"
         if split_key is None:
             split_key = self.part.plan_split(s)
         if split_key is None:
@@ -429,6 +436,8 @@ class ShardedIndexEngine(BaseIndexEngine):
         if (self.shards[s].frozen_overlay is not None
                 or self.shards[s + 1].frozen_overlay is not None):
             return False
+        assert not (self.shards[s].pending or self.shards[s + 1].pending), \
+            "merge with unapplied host writes"
         if not self.async_compact:
             self._merge_sync(s)
             return True

@@ -156,7 +156,7 @@ class TestScanBucketing:
 # the phases a request-path step is made of (``compact_build`` runs on the
 # pool thread; ``install`` nests the pack rebuild after a swap)
 STEP_PHASES = ("install", "write_apply", "write_host", "read_dispatch",
-               "read_wait", "read_unpack")
+               "read_wait", "read_unpack", "write_index")
 
 
 def _phase_delta(eng, drive) -> dict:
@@ -184,7 +184,7 @@ class TestPhaseAccounting:
         _mixed_requests(eng, keys)
         d = _phase_delta(eng, eng.step)
         for p in ("write_apply", "write_host", "read_dispatch", "read_wait",
-                  "read_unpack"):
+                  "read_unpack", "write_index"):
             assert d[p] > 0, p
         assert d["install"] == 0 and d["compact_build"] == 0
         assert sum(d[p] for p in STEP_PHASES) <= eng.step_seconds[-1]
@@ -236,8 +236,9 @@ class TestPhaseAccounting:
                  if e.name == "caller.step" or e.name.startswith("aulid.")]
         (_, lo, hi), = [s for s in spans if s[0] == "caller.step"]
         names = sorted(n for n, _, _ in spans if n != "caller.step")
-        # the read phases twice: the get batch and the one scan bucket
-        want = (["write_apply", "write_host"]
+        # the read phases twice: the get batch and the one scan bucket;
+        # the host-index drain once, behind the first read dispatch
+        want = (["write_apply", "write_host", "write_index"]
                 + ["read_dispatch", "read_wait", "read_unpack"] * 2)
         assert names == sorted(f"aulid.{p}" for p in want)
         assert all(lo <= a <= b <= hi for _, a, b in spans)
