@@ -16,8 +16,8 @@ Correctness gate (the acceptance criterion): after EVERY compaction the
 overlay-enabled read path must be bit-identical to a fresh full rebuild on a
 probe batch (lookups and scans), which this module asserts inline.
 
-Write-path scenario (ISSUE 10, DESIGN.md §14): a write-heavy stream drives
-two ``IndexEngine`` twins — ``full_repack`` re-uploads the whole padded
+Write-path scenario (DESIGN.md §14): a write-heavy stream drives two
+one-shard ``ShardedIndexEngine`` twins — ``full_repack`` re-uploads the whole padded
 overlay pack every step (the pre-merge path, ``overlay_merge=False``) while
 ``delta_merge`` ships only the step's sorted batch and merges it into the
 device-resident pack.  Reported per step: write-path H2D bytes and host
@@ -150,7 +150,8 @@ def _run_mode(mode: str, keys: np.ndarray, inserts: np.ndarray,
 def _write_path_rows(scale: str) -> tuple[list[dict], dict]:
     """Write-heavy twin run: per-step H2D bytes + host ms, full-repack vs
     delta-merge, request-for-request equivalence asserted every step."""
-    from repro.serving import IndexEngine
+    from repro.core import partition_bulkload
+    from repro.serving import ShardedIndexEngine
     n = SCALE_N[scale]
     keys = make_dataset("covid", n)
     rng = np.random.default_rng(2)
@@ -161,11 +162,10 @@ def _write_path_rows(scale: str) -> tuple[list[dict], dict]:
     assert WP_STEPS * WP_BATCH < WP_GAMMA * n, \
         "write-path scenario must not compact (fill must climb past the gate)"
 
-    def build(merge: bool) -> "IndexEngine":
-        idx = Aulid()
-        idx.bulkload(keys, payloads_for(keys))
-        return IndexEngine(idx, gamma=WP_GAMMA, backend="jnp",
-                           overlay_merge=merge)
+    def build(merge: bool) -> "ShardedIndexEngine":
+        part = partition_bulkload(keys, payloads_for(keys), 1)
+        return ShardedIndexEngine(part, gamma=WP_GAMMA, backend="jnp",
+                                  async_compact=False, overlay_merge=merge)
 
     engines = {"delta_merge": build(True), "full_repack": build(False)}
     trace = {m: [] for m in engines}     # (fill_before, d_bytes, d_host_s)
@@ -177,7 +177,7 @@ def _write_path_rows(scale: str) -> tuple[list[dict], dict]:
             [rng.choice(keys, WP_READS - len(batch)), batch])
         results = {}
         for mode, eng in engines.items():
-            fill = eng.shard.overlay_live()
+            fill = eng.shards[0].overlay_live()
             s0 = eng.stats()
             for k in batch:
                 eng.insert(int(k), int(k) + 3)
@@ -213,7 +213,7 @@ def _write_path_rows(scale: str) -> tuple[list[dict], dict]:
             "h2d_bytes_per_step": round(per_mode[mode]["h2d_bytes_per_step"]),
             "host_ms_per_step": round(per_mode[mode]["host_ms_per_step"], 3),
             "total_h2d_bytes": int(s["write_h2d_bytes"]),
-            "overlay_fill_final": int(eng.shard.overlay_live()),
+            "overlay_fill_final": int(eng.shards[0].overlay_live()),
             "overlay_merges": s["overlay_merges"],
             "overlay_reseeds": s["overlay_reseeds"],
         })

@@ -1,8 +1,8 @@
-"""Shard-parallel serving vs the monolithic engine on a skewed mixed workload.
+"""Shard-parallel serving vs one monolithic shard on a skewed mixed workload.
 
-The failure mode this PR removes (ISSUE 5): one host index + one device
-mirror means EVERY compaction stalls the whole key space behind an O(n)
-mirror rebuild — a write-hot key range taxes reads of cold ranges it never
+The failure mode range sharding removes: one host index + one device
+mirror (the engine over a single shard, the ``monolithic`` rows) means
+EVERY compaction stalls the whole key space behind an O(n) mirror rebuild — a write-hot key range taxes reads of cold ranges it never
 touches.  Range sharding (DESIGN.md §9) keeps compaction stalls shard-local.
 
 Workload: all writes are fresh keys drawn from ONE shard's range (the hot
@@ -60,10 +60,10 @@ import gc
 
 import numpy as np
 
-from repro.core import Aulid, partition_bulkload
+from repro.core import partition_bulkload
 from repro.core.workloads import (make_dataset, payloads_for,
                                   shifting_hotspot_keys)
-from repro.serving import IndexEngine, ShardedIndexEngine
+from repro.serving import ShardedIndexEngine
 
 from .common import SCALE_N, print_table, save_results, timed
 
@@ -464,9 +464,9 @@ def run(scale: str = "small") -> list[dict]:
     part = partition_bulkload(keys, pays, NUM_SHARDS)
     hot, steps = _trace(keys, part.bounds, np.random.default_rng(0))
 
-    mono_idx = Aulid()
-    mono_idx.bulkload(keys, pays)
-    mono = IndexEngine(mono_idx, gamma=GAMMA)
+    # the monolithic baseline: one shard, compacting on the request path
+    mono = ShardedIndexEngine(partition_bulkload(keys, pays, 1), gamma=GAMMA,
+                              async_compact=False)
     shrd = ShardedIndexEngine(part, gamma=GAMMA)
 
     cold = [s for s in range(shrd.num_shards) if s != hot]

@@ -79,7 +79,7 @@ class DeviceIndex:
     full_builds: int = 1      # full enumerations (this snapshot counts as one)
     # leaf rows re-mirrored by the latest refresh: None after a full build
     # (everything changed), an index array after the fast path — consumers
-    # holding device copies update only these rows (IndexEngine.compact)
+    # holding device copies update only these rows (``update_leaf_rows``)
     last_touched_rows: "np.ndarray | None" = dataclasses.field(
         default=None, repr=False)
 

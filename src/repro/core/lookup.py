@@ -25,8 +25,7 @@ jax.config.update("jax_enable_x64", True)
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import PartitionSpec  # noqa: E402
 
-from .delta_overlay import (DeltaOverlay, UINT64_MAX, merge_overlays,  # noqa: E402
-                            next_pow2)
+from .delta_overlay import DeltaOverlay, UINT64_MAX, next_pow2  # noqa: E402
 from .device_index import _STACK_2D, _STACK_3D, DeviceIndex  # noqa: E402
 
 # the mirror pools every read path gathers from — one list, derived from the
@@ -215,8 +214,8 @@ def scan_batch(arrs: dict, q: jnp.ndarray, count: int = 100, height: int = 3,
 
 def overlay_arrays(ov: DeltaOverlay) -> dict[str, jnp.ndarray]:
     """Move the overlay pools to device as ONE packed (3, cap) u64 transfer
-    (keys, payloads, tombstones) — called once per engine step, so dispatch
-    overhead matters more than layout elegance."""
+    (keys, payloads, tombstones) — the pack layout the engine's overlay pack
+    shares (``ShardedIndexEngine._rebuild_overlay_pack``)."""
     a = ov.arrays()
     pack = np.empty((3, a["ov_keys"].shape[0]), dtype=np.uint64)
     pack[0] = a["ov_keys"]
@@ -225,37 +224,12 @@ def overlay_arrays(ov: DeltaOverlay) -> dict[str, jnp.ndarray]:
     return {"ov_pack": jnp.asarray(pack), "ov_token": new_snap_token()}
 
 
-def overlay_arrays_merged(frozen: DeltaOverlay | None, live: DeltaOverlay
-                          ) -> dict:
-    """Packed (3, cap) device pack of ``frozen`` updated by ``live`` — the
-    overlay view served while a compaction is in flight (DESIGN.md §11).
-
-    Capacity is bucketed at >= 2x the live overlay's floor: the frozen side
-    holds at most ~threshold entries (it froze when it crossed gamma·n) and
-    the live side is bounded the same way, so one stable power of two covers
-    the whole in-flight window — the jitted merge path keeps one shape across
-    freeze and swap instead of recompiling per fill level.
-
-    ``n_live`` rides the dict as a host-side int (the merged occupancy — the
-    engines' ``ov_bound``); jitted consumers see it as one unused scalar."""
-    keys, pays, tomb = merge_overlays(frozen, live)
-    n = keys.shape[0]
-    cap = next_pow2(max(n, 2 * live.min_capacity))
-    pack = np.zeros((3, cap), dtype=np.uint64)
-    pack[0] = UINT64_MAX
-    pack[0, :n] = keys
-    pack[1, :n] = pays
-    pack[2, :n] = tomb
-    return {"ov_pack": jnp.asarray(pack), "ov_token": new_snap_token(),
-            "n_live": int(n)}
-
-
 @functools.partial(jax.jit, static_argnames=("cap_out",))
 def merge_overlay_pack_jnp(pack: jnp.ndarray, batch: jnp.ndarray,
                            cap_out: int) -> jnp.ndarray:
     """Device-resident sorted-merge upsert: ``pack`` (3, Ca) updated by the
     step's sorted write ``batch`` (3, Cb), producing a (3, cap_out) pack —
-    the jnp reference semantics of the overlay-merge kernel and the engines'
+    the jnp reference semantics of the overlay-merge kernel and the engine's
     default write path (DESIGN.md §14).
 
     Both inputs are u64 packs in overlay layout (keys/payloads/tombstones,
@@ -352,7 +326,7 @@ def merge_overlay_pack(ovr: dict, batch, fill_bound: int,
 
     Pads the sorted batch to a power-of-two bucket (few jit shapes), ships
     ONLY that (3, bcap) pack, and merges on device via ``merge_fn`` (default:
-    the jnp reference; the serving engines bind the Pallas kernel through
+    the jnp reference; the serving engine binds the Pallas kernel through
     ``overlay_merge_backend_fn``).  ``fill_bound`` is the caller's upper
     bound on the pack's live count after the merge: the merge runs over the
     ``merge_window`` prefix and keeps the pack's shape, and only a bound
@@ -383,7 +357,7 @@ def merge_overlay_pack(ovr: dict, batch, fill_bound: int,
 
 def overlay_merge_backend_fn(backend: str = "auto"):
     """The overlay-merge entry for a read backend, callable as
-    ``fn(pack, batch_pack, cap_out) -> new_pack`` — the engines' write-path
+    ``fn(pack, batch_pack, cap_out) -> new_pack`` — the engine's write-path
     twin of ``lookup_backend_fns``: "jnp" merges with the reference above,
     "fused" through the compiled Pallas overlay-merge kernel and
     "fused_interpret" through the same kernel in interpret mode."""
@@ -476,7 +450,7 @@ def scan_batch_overlay(arrs: dict, ovr: dict, q: jnp.ndarray, count: int = 100,
     + overlay merge.
 
     ``ov_bound`` (static) must be >= the number of LIVE overlay entries;
-    callers that track occupancy host-side (the serving engines) pass its
+    callers that track occupancy host-side (the serving engine) pass its
     next power of two, which keeps the unrolled leaf walk proportional to the
     overlay's actual fill. The default is the padded capacity — always safe,
     but an overlay sized for a large compaction threshold then unrolls a
@@ -705,7 +679,7 @@ def lookup_batch_sharded_overlay(stk: dict, ovr: dict, q: jnp.ndarray,
 
 
 # --------------------------------------------------------------------- backend
-# Read-backend dispatch (DESIGN.md §10): the serving engines bind their point-
+# Read-backend dispatch (DESIGN.md §10): the serving engine binds its point-
 # lookup entry through here, so the fused Pallas kernel and the jnp gather
 # path are interchangeable behind one switch.  "auto" is the jnp path on every
 # platform: it is the path that runs at deployment size, and the correctness
@@ -732,20 +706,17 @@ def resolve_read_backend(backend: str = "auto") -> str:
     return backend
 
 
-def lookup_backend_fns(backend: str = "auto", *, sharded: bool = False):
-    """The overlay-merged point-lookup entry for a read backend, callable as
-    ``fn(snap, ovr, q, height=...)`` — the engines' ``self._lookup`` shape.
-    "fused_interpret" runs the fused kernel in interpret mode (what tier-1
-    CI exercises)."""
+def lookup_backend_fns(backend: str = "auto"):
+    """The overlay-merged point-lookup entry over a stacked snapshot for a
+    read backend, callable as ``fn(stk, ovr, q, height=...)`` — the engine's
+    ``self._lookup`` shape.  "fused_interpret" runs the fused kernel in
+    interpret mode (what tier-1 CI exercises)."""
     b = resolve_read_backend(backend)
     if b == "jnp":
-        return lookup_batch_sharded_overlay if sharded \
-            else lookup_batch_overlay
-    from ..kernels.fused_lookup.ops import (
-        fused_lookup_batch_overlay, fused_lookup_batch_sharded_overlay)
-    fn = fused_lookup_batch_sharded_overlay if sharded \
-        else fused_lookup_batch_overlay
-    return functools.partial(fn, interpret=(b == "fused_interpret"))
+        return lookup_batch_sharded_overlay
+    from ..kernels.fused_lookup.ops import fused_lookup_batch_sharded_overlay
+    return functools.partial(fused_lookup_batch_sharded_overlay,
+                             interpret=(b == "fused_interpret"))
 
 
 @functools.partial(jax.jit,

@@ -1,26 +1,33 @@
-"""Mixed read/write serving engine over AULID + the incremental device mirror.
+"""The request half of the serving engine over AULID + its device mirror.
 
 The ROADMAP north-star is serving heavy mixed traffic; the paper's headline
-claim (§4.4, §5.3) is that AULID stays fast *under updates*.  This engine is
-the piece that makes the JAX read path honor that claim (DESIGN.md §3): before
-it, one host insert froze out the device mirror until an O(n) rebuild.
+claim (§4.4, §5.3) is that AULID stays fast *under updates*.  The engine is
+the piece that makes the JAX read path honor that claim (DESIGN.md §3): one
+host insert never freezes out the device mirror until an O(n) rebuild.
 
-Request flow per :meth:`step`:
+There is one engine, :class:`~repro.serving.sharded_engine.ShardedIndexEngine`
+(DESIGN.md §4, §9); a single index is served as its one-shard case
+(``partition_bulkload(keys, pays, 1)``).  This module holds what does not
+depend on the range partition: request admission, the step, the fused read
+batches and the step phases (:class:`BaseIndexEngine`), and the per-shard
+host state (:class:`IndexShard`).
+
+Request flow per :meth:`BaseIndexEngine.step`:
 
 1. drain the queue, partitioning into writes and reads (step-level
    consistency: every write queued before the step is visible to every read
    executed in it — the oracle the property tests assert against);
-2. record writes in the ``DeltaOverlay`` (results computed overlay-first)
-   and in the shard's host log, then dispatch the overlay pack's device
+2. record writes in their shard's ``DeltaOverlay`` (results computed
+   overlay-first) and host log, then dispatch the overlay pack's device
    merge — the device mirror itself is untouched;
-3. execute all point reads as ONE fused ``lookup_batch_overlay`` device batch
-   and scans as one ``scan_batch_overlay`` batch per power-of-two scan-length
-   bucket (mixed scan lengths share compiles; results slice to the requested
-   count); behind the first read dispatch, while the device merges and
-   reads, the host log is applied to the host ``Aulid`` (which journals the
-   writes) — no read consults the host index (DESIGN.md §3);
-4. compaction policy, with every unfrozen host log empty: once
-   ``len(overlay) >= gamma * n`` the overlay is folded into a fresh snapshot
+3. execute all point reads as ONE fused device batch and scans as one batch
+   per power-of-two scan-length bucket (mixed scan lengths share compiles;
+   results slice to the requested count); behind the first read dispatch,
+   while the device merges and reads, the host logs are applied to the host
+   ``Aulid`` indexes (which journal the writes) — no read consults a host
+   index (DESIGN.md §3);
+4. compaction policy, with every unfrozen host log empty: once a shard's
+   ``len(overlay) >= gamma * n`` its overlay is folded into a fresh snapshot
    via ``refresh_device_index`` (the journal fast path re-mirrors only
    touched leaf rows when no SMO happened) and cleared — mirroring AULID's
    own Adjust criterion of amortizing structural work against a fraction of
@@ -30,11 +37,6 @@ Write semantics are unique-key upserts (``insert`` overwrites an existing
 key's payload; ``delete`` removes the key) so host, overlay, and device views
 agree under arbitrary interleavings — AULID's duplicate-key multiset remains
 available on the host path directly.
-
-The per-index state (host index, mirror, overlay, compaction counters) lives
-in :class:`IndexShard` so the range-sharded engine (``sharded_engine.py``,
-DESIGN.md §9) reuses the same write/compaction lifecycle per shard while this
-engine stays the S=1 special case.
 """
 from __future__ import annotations
 
@@ -116,13 +118,10 @@ class IndexRequest:
 
 @dataclasses.dataclass
 class IndexShard:
-    """Per-index serving state: host structure, frozen device mirror, write
-    overlay, and compaction counters (DESIGN.md §3 lifecycle, §9 sharding).
-
-    ``arrs``/``ov_arrs`` are the device copies the monolithic engine serves
-    from; the sharded engine leaves them ``None`` and serves from the stacked
-    pools instead (``with_arrays=False``), so a shard compaction only touches
-    its own slice of the stack.
+    """Per-shard host state: host index, its mirror, the live and frozen
+    write overlays, the host log and the compaction count (DESIGN.md §3
+    lifecycle, §9 sharding).  The device copies — the stacked pools and the
+    one overlay pack — are the engine's.
 
     ``pending`` is the host log: writes recorded in the overlay whose host
     index update has not run yet (DESIGN.md §3).  The engine applies it
@@ -133,35 +132,15 @@ class IndexShard:
     idx: Aulid
     overlay: DeltaOverlay
     di: DeviceIndex
-    arrs: Optional[dict] = None
-    ov_arrs: Optional[dict] = None
     compactions: int = 0
     frozen_overlay: Optional[DeltaOverlay] = None
     pending: list = dataclasses.field(default_factory=list)
-    # device-resident write path (DESIGN.md §14): the merge backend bound by
-    # the engine (None = always reseed from host, the old full-repack path),
-    # the (live uid, frozen uid) structure the current pack was seeded
-    # against, and the write-path cost counters the benchmarks report
-    ov_merge_fn: Optional[object] = None
-    ov_struct: Optional[tuple] = None
-    write_h2d_bytes: int = 0
-    overlay_merges: int = 0
-    overlay_merge_slots: int = 0
-    overlay_reseeds: int = 0
 
     @classmethod
-    def wrap(cls, idx: Aulid, gamma: float,
-             with_arrays: bool = True) -> "IndexShard":
+    def wrap(cls, idx: Aulid, gamma: float) -> "IndexShard":
         # capacity floor ~= compaction threshold: one jit shape per lifetime
         overlay = DeltaOverlay.for_threshold(gamma * max(idx.n_items, 1))
-        di = build_device_index(idx)
-        sh = cls(idx=idx, overlay=overlay, di=di)
-        if with_arrays:
-            from ..core.lookup import device_arrays, overlay_arrays
-            sh.arrs = device_arrays(di)
-            sh.ov_arrs = overlay_arrays(overlay)
-            sh.ov_struct = (overlay.uid, 0)   # pack seeded (empty, synced)
-        return sh
+        return cls(idx=idx, overlay=overlay, di=build_device_index(idx))
 
     # ---------------------------------------------------------------- writes
     def record_write(self, op: str, key: int, payload: int = 0):
@@ -255,70 +234,14 @@ class IndexShard:
 
     def compact(self) -> None:
         """Fold the overlay into a fresh snapshot and clear it (DESIGN.md §3).
-
-        After a fast-path refresh only the touched leaf rows are re-uploaded
-        (``update_leaf_rows``); a full rebuild re-transfers every pool.  When
-        this shard serves from a stacked mirror (``arrs is None``) the device
-        update is the owner engine's job (``restack_shard``), as is the
-        refresh of the (now empty) overlay pack."""
+        The device update is the owner engine's job (``restack_shard``), as
+        is the refresh of the (now empty) overlay pack."""
         assert self.frozen_overlay is None, \
             "sync compact during in-flight compaction (drain first)"
         assert not self.pending, "compact with unapplied host writes"
-        old = self.di
-        self.di = refresh_device_index(self.idx, old)
-        if self.arrs is not None:
-            from ..core.lookup import device_arrays, update_leaf_rows
-            if self.di is old:
-                self.arrs = update_leaf_rows(self.arrs, self.di)
-            else:
-                self.arrs = device_arrays(self.di)
+        self.di = refresh_device_index(self.idx, self.di)
         self.overlay.clear()
         self.compactions += 1
-
-    def refresh_overlay_arrays(self) -> None:
-        """Sync the device overlay pack with this step's writes
-        (DESIGN.md §14).
-
-        Delta path (steady state): the device pack is the source of truth
-        between compactions — drain the live overlay's pending writes, ship
-        only that sorted batch (O(batch) H2D), and fold it in on device via
-        the bound overlay-merge backend, over the pack's live prefix
-        (``merge_window`` of the fill bound ``overlay_live()``).  The path
-        is valid exactly while the (live uid, frozen uid) structure beneath
-        the pack is unchanged: a freeze merely relabels content the pack
-        already merges (the frozen∪live view is invariant under the
-        relabeling), and batch writes stay newest, so last-writer-wins
-        keeps the pack exact.
-
-        Reseed path (ownership handoff back to the host dicts): any uid
-        change — freeze, finish_swap, abort_swap, or a clear() (which takes
-        a fresh uid) — rebuilds the pack from the host state, and
-        ``mark_synced`` discards the now-moot pending deltas."""
-        struct = (self.overlay.uid,
-                  self.frozen_overlay.uid if self.frozen_overlay else 0)
-        if (self.ov_merge_fn is not None and self.ov_arrs is not None
-                and struct == self.ov_struct):
-            from ..core.lookup import merge_overlay_pack
-            batch = self.overlay.take_batch()
-            if batch[0].size:
-                self.ov_arrs, nbytes, slots = merge_overlay_pack(
-                    self.ov_arrs, batch, self.overlay_live(),
-                    merge_fn=self.ov_merge_fn)
-                self.write_h2d_bytes += nbytes
-                self.overlay_merges += 1
-                self.overlay_merge_slots += slots
-            return
-        from ..core.lookup import overlay_arrays, overlay_arrays_merged
-        self.overlay.mark_synced()
-        if self.frozen_overlay is not None:
-            self.frozen_overlay.mark_synced()
-            self.ov_arrs = overlay_arrays_merged(self.frozen_overlay,
-                                                 self.overlay)
-        else:
-            self.ov_arrs = overlay_arrays(self.overlay)
-        self.ov_struct = struct
-        self.overlay_reseeds += 1
-        self.write_h2d_bytes += int(self.ov_arrs["ov_pack"].nbytes)
 
     def overlay_live(self) -> int:
         """Upper bound on live served-overlay entries (scan ``ov_bound``):
@@ -330,13 +253,14 @@ class IndexShard:
 
 
 class BaseIndexEngine:
-    """Request admission, fused-batch read serving, and step timing shared by
-    the monolithic and range-sharded engines (DESIGN.md §4, §9).
+    """The request half of :class:`ShardedIndexEngine` (DESIGN.md §4):
+    admission, ``step()``, the fused read batches and the phase timers.
 
-    Subclasses bind the jitted read entry points (``self._lookup`` /
-    ``self._scan``, called with the device operands `_snap()` / `_ov()`) and
-    implement the write/compaction path (`_apply_write`, `_after_writes`,
-    `_apply_host_logs`, `_maybe_compact`)."""
+    The read batches call the engine's jitted entry points (``self._lookup``
+    / ``self._scan``) on its device operands (``_snap()`` / ``_ov()``); the
+    step calls its write and compaction path (``_apply_write``,
+    ``_after_writes``, ``_apply_host_logs``, ``_maybe_compact``) and its
+    step-boundary hooks (``_begin_step`` / ``_end_step``)."""
 
     def __init__(self):
         self.queue: list[IndexRequest] = []
@@ -403,51 +327,6 @@ class BaseIndexEngine:
 
     def scan(self, key: int, count: int = 100) -> IndexRequest:
         return self.submit("scan", key, count=count)
-
-    # ---------------------------------------------------- subclass bindings
-    def _begin_step(self) -> None:
-        """Epoch-swap point of the double-buffered compaction lifecycle
-        (DESIGN.md §11): engines that build mirrors in the background install
-        any finished build here — between request batches, inside the step
-        timer (the swap cost is real serving cost), never mid-batch — so a
-        read batch only ever sees one epoch's pools."""
-
-    def _end_step(self) -> None:
-        """Step-teardown hook, run after the step's last read batch: engines
-        with a versioned boundary table release the version they pinned in
-        ``_begin_step`` here (DESIGN.md §12)."""
-
-    def _snap(self) -> dict:
-        """Device snapshot operand of the read entry points."""
-        raise NotImplementedError
-
-    def _ov(self) -> dict:
-        """Device overlay operand of the read entry points."""
-        raise NotImplementedError
-
-    def _height(self) -> int:
-        raise NotImplementedError
-
-    def _overlay_live(self) -> int:
-        """Live overlay entries — the scan's hideable-candidate bound."""
-        raise NotImplementedError
-
-    def _apply_write(self, req: IndexRequest) -> None:
-        """Route one write, record it in its shard's overlay and host log,
-        and set its result (``IndexShard.record_write``)."""
-        raise NotImplementedError
-
-    def _after_writes(self) -> None:
-        """Overlay device-pack refresh: dispatches the step's merge."""
-        raise NotImplementedError
-
-    def _apply_host_logs(self) -> int:
-        """Apply every unfrozen shard's host log; returns writes applied."""
-        raise NotImplementedError
-
-    def _maybe_compact(self) -> None:
-        """Compaction policy, run with every unfrozen host log empty."""
-        raise NotImplementedError
 
     def _drain_host_logs(self) -> None:
         """Apply the step's host logs to the host indexes (phase
@@ -556,180 +435,4 @@ class BaseIndexEngine:
             "writes_deferred": self.writes_deferred,
             "read_shape_misses": self.read_shape_misses,
             **{f"{k}_s": v for k, v in self.phase_s.items()},
-        }
-
-
-class IndexEngine(BaseIndexEngine):
-    """Batching engine for mixed get/insert/delete/scan over one index.
-
-    ``async_compact=True`` enables the double-buffered compaction lifecycle
-    (DESIGN.md §11): crossing the gamma threshold freezes the overlay and
-    builds the refreshed mirror on a background thread while steps keep
-    serving old-snapshot + frozen-overlay reads; the finished build installs
-    at the next step boundary.  Default off — the monolithic engine is the
-    S=1 reference the equivalence tests pin down, and the sharded engine is
-    where stalls actually dominate."""
-
-    def __init__(self, idx: Aulid, *, gamma: float = 0.05,
-                 auto_compact: bool = True, backend: str = "auto",
-                 async_compact: bool = False, overlay_merge: bool = True):
-        # imported lazily-adjacent (module import enables jax x64 — keep the
-        # engine importable before the host index is even built)
-        from ..core.lookup import (lookup_backend_fns,
-                                   overlay_merge_backend_fn,
-                                   resolve_read_backend, scan_batch_overlay)
-        super().__init__()
-        # point lookups dispatch by backend (jnp gathers vs fused Pallas
-        # kernel — DESIGN.md §10); scans always run the jnp path
-        self.read_backend = resolve_read_backend(backend)
-        self._lookup = lookup_backend_fns(backend)
-        self._scan = scan_batch_overlay
-        self.gamma = gamma
-        self.auto_compact = auto_compact
-        self.async_compact = async_compact
-        self.swaps = 0
-        self.failed_swaps = 0
-        self._inflight = None
-        self.shard = IndexShard.wrap(idx, gamma)
-        # device-resident write path (DESIGN.md §14): per-step writes merge
-        # into the device pack as O(batch) deltas; False keeps the old
-        # full-repack path (the write-path benchmark baseline)
-        self.overlay_merge = bool(overlay_merge)
-        if overlay_merge:
-            self.shard.ov_merge_fn = overlay_merge_backend_fn(backend)
-
-    # ------------------------------------------- shard-state delegation
-    @property
-    def idx(self) -> Aulid:
-        return self.shard.idx
-
-    @property
-    def overlay(self) -> DeltaOverlay:
-        return self.shard.overlay
-
-    @property
-    def di(self) -> DeviceIndex:
-        return self.shard.di
-
-    @property
-    def arrs(self) -> dict:
-        return self.shard.arrs
-
-    @property
-    def ov_arrs(self) -> dict:
-        return self.shard.ov_arrs
-
-    @property
-    def compactions(self) -> int:
-        return self.shard.compactions
-
-    # ------------------------------------------------------------ write path
-    def _apply_write(self, req: IndexRequest) -> None:
-        req.result = self.shard.record_write(req.op, req.key, req.payload)
-        req.done = True
-        self.writes_applied += 1
-
-    def _apply_host_logs(self) -> int:
-        return self.shard.apply_log()
-
-    def compact(self) -> None:
-        self.drain_compactions()
-        self.shard.compact()
-        self._refresh_pack()
-
-    def _refresh_pack(self) -> None:
-        with self._phase("write_host"):
-            self.shard.refresh_overlay_arrays()
-
-    def _maybe_compact(self) -> None:
-        if not (self.auto_compact and self.shard.needs_compaction(self.gamma)):
-            return
-        if not self.async_compact:
-            self.shard.compact()
-        elif self._inflight is None:     # one build in flight per engine
-            self.shard.freeze()
-            self._inflight = compaction_executor().submit(self._build_job)
-        else:
-            return
-        self._refresh_pack()   # empty, or the merged frozen+live pack
-
-    def _build_job(self):
-        """Background build+upload (DESIGN.md §11): refresh the host mirror
-        from the (frozen) index and prepare the full device pack off the
-        request path.  Only reads foreground state the in-flight window
-        freezes (``idx``, ``di``, ``arrs``).  Returns its own seconds too:
-        the install adds them on the request thread."""
-        from ..core.lookup import device_arrays, update_leaf_rows
-        t0 = time.perf_counter()
-        with phase_span("compact_build"):
-            shard = self.shard
-            old = shard.di
-            di = refresh_device_index(shard.idx, old)
-            if di is old and shard.arrs is not None:
-                arrs = update_leaf_rows(shard.arrs, di)
-            else:
-                arrs = device_arrays(di)
-        return di, arrs, time.perf_counter() - t0
-
-    def _install_ready(self, block: bool) -> None:
-        fut = self._inflight
-        if fut is None or (not block and not fut.done()):
-            return
-        with self._phase("install"):
-            self._inflight = None
-            try:
-                di, arrs, build_s = fut.result()
-            except Exception:
-                # failed build: old mirror stays live, pending replays,
-                # frozen overlay folds back under live (DESIGN.md §12) — no
-                # lost writes
-                self.shard.abort_swap()
-                self._refresh_pack()
-                self.failed_swaps += 1
-                return
-            self.phase_s["compact_build"] += build_s
-            self.shard.finish_swap(di)
-            self.shard.arrs = arrs
-            self._refresh_pack()   # frozen retired: live-only pack
-            self.swaps += 1
-
-    def _begin_step(self) -> None:
-        self._install_ready(block=False)
-
-    def drain_compactions(self) -> None:
-        """Block until any in-flight background compaction is installed."""
-        self._install_ready(block=True)
-
-    def _after_writes(self) -> None:
-        self._refresh_pack()
-
-    # ------------------------------------------------------------- read path
-    def _snap(self) -> dict:
-        return self.shard.arrs
-
-    def _ov(self) -> dict:
-        return self.shard.ov_arrs
-
-    def _height(self) -> int:
-        return max(self.di.max_inner_height, 3)
-
-    def _overlay_live(self) -> int:
-        return self.shard.overlay_live()
-
-    # ----------------------------------------------------------------- stats
-    def stats(self) -> dict:
-        return {
-            **super().stats(),
-            "read_backend": self.read_backend,
-            "overlay_len": len(self.overlay),
-            "compactions": self.compactions,
-            "swaps": self.swaps,
-            "failed_swaps": self.failed_swaps,
-            "inflight": int(self._inflight is not None),
-            "mirror_refreshes": self.di.refreshes,
-            "mirror_full_builds": self.di.full_builds,
-            "overlay_merges": self.shard.overlay_merges,
-            "overlay_merge_slots": self.shard.overlay_merge_slots,
-            "overlay_reseeds": self.shard.overlay_reseeds,
-            "write_h2d_bytes": self.shard.write_h2d_bytes,
         }

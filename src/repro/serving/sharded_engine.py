@@ -1,10 +1,13 @@
-"""Shard-parallel serving engine over a range-partitioned AULID (DESIGN.md §9).
+"""The serving engine over a range-partitioned AULID (DESIGN.md §4, §9).
 
-The monolithic :class:`~repro.serving.index_engine.IndexEngine` serves every
-request through ONE host index and ONE device mirror, so every compaction
-stalls the whole key space behind an O(n) mirror rebuild.  This engine
-partitions the key space into range shards (``core/partition.py``) and keeps
-one :class:`IndexShard` per range:
+It is the one engine: a single index is its one-shard case
+(``partition_bulkload(keys, pays, 1)``, or
+``RangePartition(np.empty(0, np.uint64), [idx])`` for an index already
+built), where one compaction rebuilds the mirror of the whole key space.
+With S shards the key space is cut into ranges (``core/partition.py``) and
+the engine keeps one :class:`IndexShard` per range; the request half —
+admission, the step and the read batches — is
+:class:`~repro.serving.index_engine.BaseIndexEngine`:
 
 * **writes** route to their shard's overlay + host log with one
   ``searchsorted`` over the boundary table; the logs reach the host indexes
@@ -20,10 +23,12 @@ one :class:`IndexShard` per range:
   shard overlays concatenated into one globally sorted pack (shards partition
   the key space in order, so concatenation in shard order IS the sort).
 
-Request semantics are identical to the monolithic engine, request for request
-(property-tested in ``tests/test_sharded_engine.py``), and — per the
-compaction-storm suite in ``tests/test_async_compaction.py`` — identical
-whether compactions run synchronously or double-buffered (DESIGN.md §11):
+Request semantics do not depend on the shard count: the one-shard and
+three-shard engines answer alike, request for request, and both match the
+host index and a dict oracle (``tests/test_sharded_engine.py``).  Per the
+compaction-storm suite in ``tests/test_async_compaction.py`` they are also
+identical whether compactions run synchronously or double-buffered
+(DESIGN.md §11):
 with ``async_compact=True`` (the default) a shard crossing its gamma
 threshold freezes its overlay, builds + uploads its refreshed mirror slice on
 a background thread, and installs it at a later step boundary while reads
@@ -92,7 +97,7 @@ class ShardedIndexEngine(BaseIndexEngine):
         self.read_backend = resolve_read_backend(backend)
         self.mesh = mesh
         if mesh is None:
-            self._lookup = lookup_backend_fns(backend, sharded=True)
+            self._lookup = lookup_backend_fns(backend)
             self._scan = scan_batch_sharded_overlay
             self._stacked_device_arrays = stacked_device_arrays
             self._update_stacked_shard = update_stacked_shard
@@ -128,8 +133,7 @@ class ShardedIndexEngine(BaseIndexEngine):
         self._step_version = None     # boundary version pinned by this step
         self._min_slots = 0           # shard-slot capacity ratchet
         self._write_counts = [0] * part.num_shards  # inserts since sample
-        self.shards = [IndexShard.wrap(idx, gamma, with_arrays=False)
-                       for idx in part.shards]
+        self.shards = [IndexShard.wrap(idx, gamma) for idx in part.shards]
         self.sdi = stack_device_indexes(
             [sh.di for sh in self.shards], part.bounds,
             min_shards=self._shard_slots(len(self.shards)))
@@ -175,6 +179,8 @@ class ShardedIndexEngine(BaseIndexEngine):
 
     # ------------------------------------------------------------ write path
     def _apply_write(self, req: IndexRequest) -> None:
+        """Route one write, record it in its shard's overlay and host log,
+        and set its result (``IndexShard.record_write``)."""
         s = self.part.shard_of(req.key)
         sh = self.shards[s]
         req.result = sh.record_write(req.op, req.key, req.payload)
@@ -184,15 +190,19 @@ class ShardedIndexEngine(BaseIndexEngine):
             self._write_counts[s] += 1   # load-monitor insert-rate window
 
     def _after_writes(self) -> None:
+        """Overlay device-pack refresh: dispatches the step's merge."""
         self.ov_arrs = self._merged_overlay_pack()
 
     def _apply_host_logs(self) -> int:
+        """Apply every unfrozen shard's host log; returns writes applied."""
         # a frozen shard's log waits for its swap (finish_swap, abort_swap,
         # or _route_window_writes)
         return sum(sh.apply_log() for sh in self.shards)
 
     def _maybe_compact(self) -> None:
-        """Shard-local compaction: only shards past their own gamma threshold
+        """Compaction policy, run with every unfrozen host log empty.
+
+        Shard-local compaction: only shards past their own gamma threshold
         fold their overlay.  Synchronous mode re-uploads their mirror slices
         inline; double-buffered mode (default) freezes each shard's overlay
         and hands the build+upload to a background thread (DESIGN.md §11) —
@@ -304,6 +314,11 @@ class ShardedIndexEngine(BaseIndexEngine):
             self.ov_arrs = self._merged_overlay_pack()
 
     def _begin_step(self) -> None:
+        """Epoch-swap point of the double-buffered compaction lifecycle
+        (DESIGN.md §11): install any finished build here — between request
+        batches, inside the step timer (the swap cost is real serving cost),
+        never mid-batch — so a read batch only ever sees one epoch's pools;
+        then sample the load monitor and pin the step's boundary version."""
         self._install_ready(block=False)
         if self.repartition and self.steps % self.repartition_check_every == 0:
             self._maybe_repartition()
@@ -312,6 +327,8 @@ class ShardedIndexEngine(BaseIndexEngine):
         self._step_version = self.part.pin()
 
     def _end_step(self) -> None:
+        """Step teardown, after the step's last read batch: release the
+        boundary-table version pinned in ``_begin_step`` (DESIGN.md §12)."""
         if self._step_version is not None:
             self.part.unpin(self._step_version)
             self._step_version = None
@@ -802,15 +819,18 @@ class ShardedIndexEngine(BaseIndexEngine):
             ov_bound=ov_bound, qcap=self._mesh_qcap(q, snap))
 
     def _snap(self) -> dict:
+        """Device snapshot operand of the read entry points."""
         return self.stk
 
     def _ov(self) -> dict:
+        """Device overlay operand of the read entry points."""
         return self.ov_arrs
 
     def _height(self) -> int:
         return max(self.sdi.max_inner_height, 3)
 
     def _overlay_live(self) -> int:
+        """Live overlay entries — the scan's hideable-candidate bound."""
         # tracked pack occupancy: on rebuild the recorded fill IS the served
         # frozen+live entry count; on a delta merge it is the host dicts'
         # upper bound on it (always >= the pack's true fill — safe ov_bound)

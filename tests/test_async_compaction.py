@@ -22,7 +22,7 @@ import pytest
 from repro.core import Aulid, AulidConfig, BlockDevice, partition_bulkload
 from repro.core.device_index import build_device_index, refresh_device_index
 from repro.core.workloads import make_dataset, payloads_for
-from repro.serving import IndexEngine, ShardedIndexEngine
+from repro.serving import ShardedIndexEngine
 from repro.serving import index_engine as ie_mod
 from repro.serving.index_engine import IndexShard
 
@@ -71,12 +71,9 @@ def _sharded(gamma, async_compact, num_shards=3, n=1_500):
                                     async_compact=async_compact)
 
 
-def _mono(gamma, async_compact, n=1_500):
-    keys, pay = _dataset(n)
-    idx = Aulid(BlockDevice(), cfg=AulidConfig(**SMALL_GEOM))
-    idx.bulkload(keys, pay)
-    return keys, IndexEngine(idx, gamma=gamma, backend="jnp",
-                             async_compact=async_compact)
+def _one_shard(gamma, async_compact, n=1_500):
+    """The single-index engine: one shard, one mirror, one build in flight."""
+    return _sharded(gamma, async_compact, num_shards=1, n=n)
 
 
 def _storm_writes(eng: ShardedIndexEngine, keys, rng):
@@ -190,8 +187,8 @@ class TestShardedStormEquivalence:
 
 class TestMonolithicAsync:
     def test_async_equivalence_and_lifecycle(self, manual_pool):
-        keys, sync = _mono(0.02, async_compact=False)
-        _, dbuf = _mono(0.02, async_compact=True)
+        keys, sync = _one_shard(0.02, async_compact=False)
+        _, dbuf = _one_shard(0.02, async_compact=True)
         rng = np.random.default_rng(11)
         need = int(0.02 * len(keys)) + 2
         dels = rng.choice(keys, 4, replace=False).tolist()
@@ -206,12 +203,13 @@ class TestMonolithicAsync:
                     + [("scan", int(rng.choice(keys)), 0, 16)])
         assert _drive(sync, [storm, inflight]) == \
             _drive(dbuf, [storm, inflight])
-        assert dbuf.stats()["inflight"] == 1 and dbuf.shard.pending
+        assert dbuf.stats()["inflight"] == 1 and dbuf.shards[0].pending
         manual_pool.pump()
         post = [("get", int(k)) for k in rng.choice(keys, 8)]
         assert _drive(sync, [post]) == _drive(dbuf, [post])
         assert dbuf.stats()["swaps"] == 1
-        assert dbuf.shard.frozen_overlay is None and not dbuf.shard.pending
+        assert (dbuf.shards[0].frozen_overlay is None
+                and not dbuf.shards[0].pending)
 
 
 class TestDeferredWrites:
@@ -223,7 +221,7 @@ class TestDeferredWrites:
         keys, pay = _dataset(600)
         idx = Aulid(BlockDevice(), cfg=AulidConfig(**SMALL_GEOM))
         idx.bulkload(keys, pay)
-        return keys, IndexShard.wrap(idx, gamma=0.05, with_arrays=False)
+        return keys, IndexShard.wrap(idx, gamma=0.05)
 
     def test_deferred_results_match_sync_semantics(self):
         keys, sh = self._shard()
@@ -316,8 +314,8 @@ class TestFailedBuilds:
                    for sh in dbuf.shards)
 
     def test_monolithic_failed_build_keeps_writes(self, manual_pool):
-        keys, sync = _mono(0.02, async_compact=False)
-        _, dbuf = _mono(0.02, async_compact=True)
+        keys, sync = _one_shard(0.02, async_compact=False)
+        _, dbuf = _one_shard(0.02, async_compact=True)
         rng = np.random.default_rng(13)
         need = int(0.02 * len(keys)) + 2
         news = [int(k) for k in rng.integers(1, 2**48, need, dtype=np.uint64)]
@@ -330,7 +328,7 @@ class TestFailedBuilds:
         post = ([("get", k) for k in news[:4]] + [("get", k) for k in dels]
                 + [("scan", int(rng.choice(keys)), 0, 16)])
 
-        def boom():
+        def boom(s, sdi):
             raise RuntimeError("injected build failure")
         dbuf._build_job = boom
         assert _drive(sync, [storm, inflight]) == \
@@ -340,13 +338,13 @@ class TestFailedBuilds:
         assert _drive(sync, [post]) == _drive(dbuf, [post])
         st = dbuf.stats()
         assert st["failed_swaps"] == 1 and st["swaps"] == 0
-        assert not dbuf.shard.pending          # replayed, not lost
+        assert not dbuf.shards[0].pending      # replayed, not lost
         kick = [("insert", int(keys[0]), 4242)]   # re-freeze via write step
         assert _drive(sync, [kick]) == _drive(dbuf, [kick])
         manual_pool.pump()                     # recovery build
         assert _drive(sync, [post]) == _drive(dbuf, [post])
         assert dbuf.stats()["swaps"] == 1
-        assert dbuf.shard.frozen_overlay is None
+        assert dbuf.shards[0].frozen_overlay is None
 
 
 class TestEpochInvariants:

@@ -3,8 +3,9 @@
 A step records its writes in the overlay and in each shard's host log,
 dispatches the overlay merge and the first read program, and only then
 applies the logs to the host ``Aulid`` indexes (phase ``write_index``); the
-compaction decision follows the drain.  These tests hold both engines,
-under sync and async compaction and with repartitioning on, to a sorted-dict
+compaction decision follows the drain.  These tests hold the engine over
+one shard and over three, under sync and async compaction and with
+repartitioning on, to a sorted-dict
 oracle request for request, check the host indexes at every step end, pin
 down the order of the phases, and cover the write that would be lost if a
 shard froze with an unapplied log.
@@ -17,9 +18,9 @@ import pytest
 
 from test_async_compaction import ManualExecutor
 
-from repro.core import Aulid, AulidConfig, BlockDevice, partition_bulkload
+from repro.core import AulidConfig, partition_bulkload
 from repro.core.workloads import make_dataset, payloads_for
-from repro.serving import IndexEngine, ShardedIndexEngine
+from repro.serving import ShardedIndexEngine
 from repro.serving import index_engine as ie_mod
 
 SMALL_GEOM = dict(leaf_capacity=16, pa_classes=(4, 8), bt_child_capacity=15)
@@ -38,16 +39,10 @@ def _data(n):
 
 
 def _engine(which, keys, pay, gamma, **kw):
-    if which == "mono":
-        idx = Aulid(BlockDevice(), cfg=AulidConfig(**SMALL_GEOM))
-        idx.bulkload(keys, pay)
-        return IndexEngine(idx, gamma=gamma, backend="jnp", **kw)
-    part = partition_bulkload(keys, pay, 3, cfg=AulidConfig(**SMALL_GEOM))
+    num_shards = {"one_shard": 1, "three_shards": 3}[which]
+    part = partition_bulkload(keys, pay, num_shards,
+                              cfg=AulidConfig(**SMALL_GEOM))
     return ShardedIndexEngine(part, gamma=gamma, backend="jnp", **kw)
-
-
-def _shards(eng):
-    return [eng.shard] if isinstance(eng, IndexEngine) else eng.shards
 
 
 def _host_items(sh) -> dict:
@@ -113,14 +108,14 @@ def _step_requests(rng, keys, hot_hi, step):
 
 
 CASES = {
-    "mono-sync": ("mono", dict(async_compact=False)),
-    "mono-async": ("mono", dict(async_compact=True)),
-    "sharded-sync": ("sharded", dict(async_compact=False)),
-    "sharded-async": ("sharded", dict(async_compact=True)),
-    "sharded-sync-repartition": ("sharded", dict(
+    "one_shard-sync": ("one_shard", dict(async_compact=False)),
+    "one_shard-async": ("one_shard", dict(async_compact=True)),
+    "three_shards-sync": ("three_shards", dict(async_compact=False)),
+    "three_shards-async": ("three_shards", dict(async_compact=True)),
+    "three_shards-sync-repartition": ("three_shards", dict(
         async_compact=False, repartition=True, split_ratio=1.5,
         min_split_items=16)),
-    "sharded-async-repartition": ("sharded", dict(
+    "three_shards-async-repartition": ("three_shards", dict(
         async_compact=True, repartition=True, split_ratio=1.5,
         min_split_items=16)),
 }
@@ -144,14 +139,11 @@ def test_mixed_steps_match_sorted_dict_oracle(manual_pool, case):
         want = oracle.step(trace)
         for r, w, args in zip(reqs, want, trace):
             assert r.done and r.result == w, (step, args)
-        for s, sh in enumerate(_shards(eng)):
+        for s, sh in enumerate(eng.shards):
             if sh.frozen_overlay is None:
                 assert not sh.pending, (step, s)
-            if which == "mono":
-                mine = oracle.d
-            else:
-                mine = {k: v for k, v in oracle.d.items()
-                        if eng.part.shard_of(k) == s}
+            mine = {k: v for k, v in oracle.d.items()
+                    if eng.part.shard_of(k) == s}
             assert _host_items(sh) == mine, (step, s)
         if step % 2:
             manual_pool.pump()      # builds span a whole step, then land
@@ -160,19 +152,19 @@ def test_mixed_steps_match_sorted_dict_oracle(manual_pool, case):
     if kw.get("repartition"):
         assert st["splits"] + st["merges"] > 0
     eng.drain_compactions()
-    for sh in _shards(eng):
+    for sh in eng.shards:
         assert sh.frozen_overlay is None and not sh.pending
         sh.idx.check_invariants()
 
 
-@pytest.mark.parametrize("which", ["mono", "sharded"])
+@pytest.mark.parametrize("which", ["one_shard", "three_shards"])
 def test_writes_of_the_freezing_step_survive_the_swap(manual_pool, which):
     """A shard crosses gamma·n in step t and freezes: the drain ran first,
     so the build sees step t's writes in the host index, and after the
     swap retires the overlay that held them they are still read back."""
     keys, pay = _data(600)
     eng = _engine(which, keys, pay, gamma=0.02, async_compact=True)
-    sh = _shards(eng)[0]
+    sh = eng.shards[0]
     hi = int(keys[150])
     rng = np.random.default_rng(21)
     fresh = sorted({int(k) for k in rng.integers(int(keys[0]), hi, 60)}
@@ -218,14 +210,14 @@ def _recording(monkeypatch, eng, in_host):
     return seen, at_dispatch
 
 
-@pytest.mark.parametrize("which", ["mono", "sharded"])
+@pytest.mark.parametrize("which", ["one_shard", "three_shards"])
 def test_drain_runs_behind_the_first_read_dispatch(monkeypatch, which):
     keys, pay = _data(600)
     eng = _engine(which, keys, pay, gamma=10.0)     # no compaction
     probe = [int(keys[40]) + 1]
 
     def in_host():
-        return any(sh.idx.lookup(probe[0]) is not None for sh in _shards(eng))
+        return any(sh.idx.lookup(probe[0]) is not None for sh in eng.shards)
     read = ["read_dispatch", "read_wait", "read_unpack"]
     seen, at_dispatch = _recording(monkeypatch, eng, in_host)
     # writes, gets and a scan bucket: the drain sits between the get
@@ -264,7 +256,7 @@ def test_drain_runs_behind_the_first_read_dispatch(monkeypatch, which):
     assert seen == read
 
 
-@pytest.mark.parametrize("which", ["mono", "sharded"])
+@pytest.mark.parametrize("which", ["one_shard", "three_shards"])
 def test_stats_report_write_index_and_writes_deferred(manual_pool, which):
     keys, pay = _data(600)
     eng = _engine(which, keys, pay, gamma=0.05, async_compact=True)
@@ -280,11 +272,11 @@ def test_stats_report_write_index_and_writes_deferred(manual_pool, which):
     # a step that freezes the lowest shard: its next writes wait for the
     # swap, so they are applied but not deferred
     hi = int(keys[150])
-    need = int(0.05 * _shards(eng)[0].idx.n_items) + 2
+    need = int(0.05 * eng.shards[0].idx.n_items) + 2
     for k in np.linspace(int(keys[0]) + 1, hi - 1, need).astype(np.uint64):
         eng.insert(int(k), 1)
     eng.step()
-    assert _shards(eng)[0].frozen_overlay is not None
+    assert eng.shards[0].frozen_overlay is not None
     before = eng.stats()
     eng.insert(int(keys[2]), 8)
     eng.step()

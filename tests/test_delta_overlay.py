@@ -10,12 +10,13 @@ import pytest
 
 from hypothesis_compat import given, settings, st
 
-from repro.core import Aulid, AulidConfig, BlockDevice, DeltaOverlay
+from repro.core import (Aulid, AulidConfig, BlockDevice, DeltaOverlay,
+                        RangePartition)
 from repro.core.device_index import build_device_index, refresh_device_index
 from repro.core.lookup import (device_arrays, lookup_batch_overlay,
                                overlay_arrays, scan_batch_overlay)
 from repro.core.workloads import make_dataset, payloads_for
-from repro.serving import IndexEngine
+from repro.serving import ShardedIndexEngine
 
 import jax.numpy as jnp
 
@@ -342,9 +343,12 @@ class TestEmptyMirror:
 
 class TestIndexEngine:
     def _mk(self, n=3_000, **kw):
+        """The engine over one host index (a one-shard partition),
+        compacting synchronously."""
         keys = make_dataset("covid", n, seed=1)
-        idx = small_build(keys)
-        return keys, IndexEngine(idx, **kw)
+        self.idx = small_build(keys)
+        part = RangePartition(np.empty(0, dtype=np.uint64), [self.idx])
+        return keys, ShardedIndexEngine(part, async_compact=False, **kw)
 
     def test_mixed_interleaving_vs_dict_oracle(self):
         keys, eng = self._mk(gamma=0.02)
@@ -386,7 +390,7 @@ class TestIndexEngine:
         stats = eng.stats()
         assert stats["compactions"] >= 1, "gamma policy never fired"
         assert stats["writes_applied"] > 0 and stats["reads_served"] > 0
-        eng.idx.check_invariants()
+        self.idx.check_invariants()
 
     def test_step_level_consistency(self):
         """A get queued before a write in the same batch still sees it."""
@@ -404,7 +408,7 @@ class TestIndexEngine:
         eng.delete(k)
         eng.get(k)
         eng.step()
-        assert len(eng.overlay) == 0 and eng.compactions >= 1
+        assert len(eng.shards[0].overlay) == 0 and eng.compactions >= 1
         r = eng.get(k)
         eng.step()
         assert r.result is None

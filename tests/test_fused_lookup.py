@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from repro.core import (Aulid, AulidConfig, BlockDevice, DeltaOverlay,
-                        partition_bulkload)
+                        RangePartition, partition_bulkload)
 from repro.core.device_index import build_device_index, stack_device_indexes
 from repro.core.lookup import (READ_BACKENDS, device_arrays, lookup_batch,
                                lookup_batch_overlay, lookup_batch_sharded,
@@ -29,7 +29,7 @@ from repro.kernels.fused_lookup import (PoolGeometry, TileStrategy,
                                         fused_lookup_batch_sharded_overlay)
 from repro.kernels.fused_lookup import tuning
 from repro.kernels.fused_lookup import ops as ops_mod
-from repro.serving import IndexEngine, ShardedIndexEngine
+from repro.serving import ShardedIndexEngine
 
 import jax.numpy as jnp
 
@@ -340,14 +340,16 @@ class TestBackendDispatch:
         with pytest.raises(ValueError):
             resolve_read_backend("cuda_graphs")
         with pytest.raises(ValueError):
-            IndexEngine(Aulid(), backend="nope")
+            ShardedIndexEngine(
+                RangePartition(np.empty(0, dtype=np.uint64), [Aulid()]),
+                backend="nope")
 
     def test_backend_fns_parity(self):
-        keys, idx, di, arrs, h = _mono("covid")
+        keys, part, sdi, stk, h = _stack()
         ovr = overlay_arrays(DeltaOverlay())
         q = _queries(keys, np.random.default_rng(5), 48, 16)
-        _same(lookup_backend_fns("fused_interpret")(arrs, ovr, q, height=h),
-              lookup_backend_fns("jnp")(arrs, ovr, q, height=h))
+        _same(lookup_backend_fns("fused_interpret")(stk, ovr, q, height=h),
+              lookup_backend_fns("jnp")(stk, ovr, q, height=h))
 
     def _drive(self, eng, keys, rng, steps=3):
         out = []
@@ -367,7 +369,9 @@ class TestBackendDispatch:
         def build(backend):
             idx = Aulid(BlockDevice(), cfg=AulidConfig(**SMALL_GEOM))
             idx.bulkload(keys, payloads_for(keys))
-            return IndexEngine(idx, gamma=0.02, backend=backend)
+            part = RangePartition(np.empty(0, dtype=np.uint64), [idx])
+            return ShardedIndexEngine(part, gamma=0.02, backend=backend,
+                                      async_compact=False)
 
         a, b = build("jnp"), build("fused_interpret")
         assert (a.read_backend, b.read_backend) == ("jnp", "fused_interpret")

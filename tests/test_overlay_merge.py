@@ -10,9 +10,10 @@ only as compaction input and as the oracle here.
 
 Layers under test: the rank-arithmetic jnp merge and the Pallas kernel
 (interpret mode) against a literal dict repack; ``DeltaOverlay``'s
-incremental sorted mirror against a from-scratch rebuild; and both serving
-engines' delta write path against full-repack twins across compaction
-swaps (hand-pumped pool) and an online split.
+incremental sorted mirror against a from-scratch rebuild; and the serving
+engine's delta write path, over one shard and over three, against
+full-repack twins across compaction swaps (hand-pumped pool) and an online
+split.
 """
 import numpy as np
 import pytest
@@ -20,8 +21,7 @@ import pytest
 from hypothesis_compat import given, settings, st
 from test_async_compaction import ManualExecutor
 
-from repro.core import (Aulid, AulidConfig, BlockDevice, DeltaOverlay,
-                        partition_bulkload)
+from repro.core import AulidConfig, DeltaOverlay, partition_bulkload
 from repro.core.delta_overlay import next_pow2
 from repro.core.lookup import (empty_overlay_pack, merge_overlay_pack,
                                merge_overlay_pack_jnp, merge_window,
@@ -29,7 +29,7 @@ from repro.core.lookup import (empty_overlay_pack, merge_overlay_pack,
 from repro.core.workloads import make_dataset, payloads_for
 from repro.kernels.overlay_merge import (overlay_merge_pack,
                                          overlay_merge_pack_stacked)
-from repro.serving import IndexEngine, ShardedIndexEngine
+from repro.serving import ShardedIndexEngine
 from repro.serving import index_engine as ie_mod
 
 import jax.numpy as jnp
@@ -283,18 +283,18 @@ class TestDeltaOverlayBatching:
                                       np.array([1, 2], np.uint64))
 
 
-def small_build(keys):
-    idx = Aulid(BlockDevice(), cfg=AulidConfig(**SMALL_GEOM))
-    idx.bulkload(keys, payloads_for(keys))
-    return idx
+def one_shard(keys, **kw):
+    """The engine over a single index (a one-shard partition); it compacts
+    synchronously unless ``kw`` says otherwise."""
+    part = partition_bulkload(keys, payloads_for(keys), 1,
+                              cfg=AulidConfig(**SMALL_GEOM))
+    return ShardedIndexEngine(part, **{"async_compact": False, **kw})
 
 
 def twin_engines(n=1_200, backend="jnp", **kw):
     keys = make_dataset("covid", n, seed=1)
-    eng = IndexEngine(small_build(keys), backend=backend,
-                      overlay_merge=True, **kw)
-    base = IndexEngine(small_build(keys), backend=backend,
-                       overlay_merge=False, **kw)
+    eng = one_shard(keys, backend=backend, overlay_merge=True, **kw)
+    base = one_shard(keys, backend=backend, overlay_merge=False, **kw)
     return keys, eng, base
 
 
@@ -331,7 +331,7 @@ class TestEngineWritePath:
         assert s["overlay_merges"] > 0
         assert sb["overlay_merges"] == 0 and sb["overlay_reseeds"] > 0
         assert s["write_h2d_bytes"] < sb["write_h2d_bytes"]
-        eng.idx.check_invariants()
+        eng.shards[0].idx.check_invariants()
 
     def test_mid_stream_swap_parity(self, monkeypatch):
         """The delta path hands off across freeze -> build -> swap: while a
@@ -339,7 +339,8 @@ class TestEngineWritePath:
         frozen ∪ live, and the post-swap reseed starts a new delta run."""
         pool = ManualExecutor()
         monkeypatch.setattr(ie_mod, "_COMPACT_POOL", pool)
-        keys, eng, base = twin_engines(gamma=0.01)   # freeze early
+        keys, eng, base = twin_engines(gamma=0.01,   # freeze early
+                                       async_compact=True)
         oracle = {int(k): int(k) + 1 for k in keys}
         rng = np.random.default_rng(8)
         for step in range(8):
@@ -374,10 +375,10 @@ class TestEngineWritePath:
         answers exactly like the host dict, across the compaction swaps a
         tiny gamma forces mid-stream."""
         keys = make_dataset("covid", 600, seed=1)
-        eng = IndexEngine(small_build(keys), backend=backend, gamma=0.02,
-                          overlay_merge=True)
+        eng = one_shard(keys, backend=backend, gamma=0.02,
+                        overlay_merge=True)
         oracle = {int(k): int(k) + 1 for k in keys}
-        checks = []
+        checks, step_gets = [], []
         for j, (op, k, p) in enumerate(ops):
             if op == "i":
                 eng.insert(k, p)
@@ -386,13 +387,15 @@ class TestEngineWritePath:
                 eng.delete(k)
                 oracle.pop(k, None)
             else:
-                checks.append((k, eng.get(k)))
-            if (j + 1) % 8 == 0:
+                step_gets.append((k, eng.get(k)))
+            if (j + 1) % 8 == 0 or j == len(ops) - 1:
                 eng.step()
-        eng.run()
-        for k, r in checks:
-            assert r.done and r.result == oracle.get(k), k
-        eng.idx.check_invariants()
+                # a get sees every write of its own step, none of later ones
+                checks += [(k, r, oracle.get(k)) for k, r in step_gets]
+                step_gets = []
+        for k, r, want in checks:
+            assert r.done and r.result == want, k
+        eng.shards[0].idx.check_invariants()
 
 
 class TestShardedWritePath:
@@ -473,20 +476,17 @@ class TestShardedWritePath:
         assert eng.stats()["num_shards"] > 3
         assert eng.stats()["overlay_merges"] > 0
 
-    @pytest.mark.parametrize("engine", ["sharded", "monolithic"])
+    @pytest.mark.parametrize("engine", ["one_shard", "three_shards"])
     def test_merge_window_below_capacity(self, engine):
         """Write steps far below the compaction threshold: every answer
         matches the dict oracle, every step merges on device, and the mean
         merge window stays below the pack's capacity."""
         keys = make_dataset("covid", 1_200, seed=1)
-        if engine == "sharded":
-            part = partition_bulkload(keys, payloads_for(keys), 3,
-                                      cfg=AulidConfig(**SMALL_GEOM))
-            eng = ShardedIndexEngine(part, gamma=0.5, backend="jnp",
-                                     overlay_merge=True)
-        else:
-            eng = IndexEngine(small_build(keys), gamma=0.5, backend="jnp",
-                              overlay_merge=True)
+        num_shards = {"one_shard": 1, "three_shards": 3}[engine]
+        part = partition_bulkload(keys, payloads_for(keys), num_shards,
+                                  cfg=AulidConfig(**SMALL_GEOM))
+        eng = ShardedIndexEngine(part, gamma=0.5, backend="jnp",
+                                 overlay_merge=True)
         oracle = {int(k): int(k) + 1 for k in keys}
         rng = np.random.default_rng(11)
         for step in range(8):

@@ -1,31 +1,44 @@
-"""ShardedIndexEngine vs the monolithic IndexEngine, request for request.
+"""ShardedIndexEngine over one shard vs over three, request for request.
 
-The acceptance oracle of the range-sharding refactor (DESIGN.md §9): on any
-interleaving of get/insert/delete/scan requests the sharded engine must
-return exactly what the monolithic engine returns, while compacting shard-
-locally (a hot shard folding its overlay leaves cold shards' mirrors at
-their snapshot epoch).
+The acceptance oracle of range sharding (DESIGN.md §9): on any interleaving
+of get/insert/delete/scan requests the three-shard engine must return
+exactly what the one-shard engine (a single index) returns, while compacting
+shard-locally (a hot shard folding its overlay leaves cold shards' mirrors
+at their snapshot epoch).  The one-shard engine is in turn held to the host
+``Aulid`` and to a dict oracle, also when it wraps an index that took writes
+after its bulkload.
 """
+import bisect
+
 import numpy as np
 import pytest
 
-from repro.core import Aulid, AulidConfig, BlockDevice, partition_bulkload
+from repro.core import (Aulid, AulidConfig, BlockDevice, RangePartition,
+                        partition_bulkload)
 from repro.core.workloads import make_dataset, payloads_for
-from repro.serving import IndexEngine, ShardedIndexEngine
+from repro.serving import ShardedIndexEngine
 from repro.serving.index_engine import PHASES, pad_queries, scan_bucket
 
 SMALL_GEOM = dict(leaf_capacity=16, pa_classes=(4, 8), bt_child_capacity=15)
 
 
+def one_shard(idx: Aulid) -> RangePartition:
+    """A single host index as a one-shard partition: no boundaries."""
+    return RangePartition(np.empty(0, dtype=np.uint64), [idx])
+
+
 def mk_engines(n=1_500, num_shards=3, gamma=0.05, **kw):
+    """(keys, one-shard engine, ``num_shards``-shard engine) over the same
+    keys."""
     keys = make_dataset("covid", n, seed=1)
     pay = payloads_for(keys)
-    part = partition_bulkload(keys, pay, num_shards,
-                              cfg=AulidConfig(**SMALL_GEOM))
-    mono_idx = Aulid(BlockDevice(), cfg=AulidConfig(**SMALL_GEOM))
-    mono_idx.bulkload(keys, pay)
-    return (keys, IndexEngine(mono_idx, gamma=gamma, **kw),
-            ShardedIndexEngine(part, gamma=gamma, **kw))
+    cfg = AulidConfig(**SMALL_GEOM)
+    return (keys,
+            ShardedIndexEngine(partition_bulkload(keys, pay, 1, cfg=cfg),
+                               gamma=gamma, **kw),
+            ShardedIndexEngine(partition_bulkload(keys, pay, num_shards,
+                                                  cfg=cfg),
+                               gamma=gamma, **kw))
 
 
 class TestShardedEquivalence:
@@ -33,52 +46,52 @@ class TestShardedEquivalence:
     def test_randomized_mixed_trace(self, seed):
         """Property: both engines answer a randomized mixed trace with
         identical results (fixed per-step op mix keeps jit shapes shared)."""
-        keys, mono, shrd = mk_engines()
+        keys, one, shrd = mk_engines()
         rng = np.random.default_rng(seed)
         pairs = []
         for step in range(3):
             for i in range(18):       # 18 gets
                 k = (int(rng.choice(keys)) if rng.random() < 0.6
                      else int(rng.integers(0, 2**50)))
-                pairs.append((mono.get(k), shrd.get(k)))
+                pairs.append((one.get(k), shrd.get(k)))
             for i in range(10):       # 10 upserts (new + existing keys)
                 k = (int(rng.integers(0, 2**50)) if rng.random() < 0.7
                      else int(rng.choice(keys)))
                 p = step * 100 + i
-                pairs.append((mono.insert(k, p), shrd.insert(k, p)))
+                pairs.append((one.insert(k, p), shrd.insert(k, p)))
             for i in range(5):        # 5 deletes
                 k = (int(rng.choice(keys)) if rng.random() < 0.6
                      else int(rng.integers(0, 2**50)))
-                pairs.append((mono.delete(k), shrd.delete(k)))
+                pairs.append((one.delete(k), shrd.delete(k)))
             for i in range(4):        # 4 scans, one shared length bucket
                 k = int(rng.choice(keys)) if rng.random() < 0.8 \
                     else int(rng.integers(0, 2**50))
                 c = int(rng.integers(9, 16))
-                pairs.append((mono.scan(k, c), shrd.scan(k, c)))
-            mono.step()
+                pairs.append((one.scan(k, c), shrd.scan(k, c)))
+            one.step()
             shrd.step()
         for m, s in pairs:
             assert m.done and s.done
             assert m.result == s.result, (m.op, m.key, m.count)
-        assert mono.reads_served == shrd.reads_served
-        assert mono.writes_applied == shrd.writes_applied
+        assert one.reads_served == shrd.reads_served
+        assert one.writes_applied == shrd.writes_applied
         for sh in shrd.shards:
             sh.idx.check_invariants()
 
     def test_scan_across_boundary_with_step_writes(self):
         """A scan straddling a shard boundary sees same-step writes on BOTH
         sides of the boundary (overlay merge + successor chain)."""
-        keys, mono, shrd = mk_engines(gamma=10.0)   # no compaction
+        keys, one, shrd = mk_engines(gamma=10.0)   # no compaction
         b = int(shrd.part.bounds[0])
         i = int(np.searchsorted(keys, np.uint64(b)))
         start = int(keys[i - 2])
-        for eng in (mono, shrd):
+        for eng in (one, shrd):
             eng.insert(b - 1 if b - 1 not in keys else b, 111)
             eng.insert(b + 1, 222)
             eng.delete(int(keys[i - 1]))
-        r_m = mono.scan(start, 10)
+        r_m = one.scan(start, 10)
         r_s = shrd.scan(start, 10)
-        mono.step()
+        one.step()
         shrd.step()
         assert r_m.result == r_s.result
         got_keys = [k for k, _ in r_s.result]
@@ -86,12 +99,74 @@ class TestShardedEquivalence:
         assert int(keys[i - 1]) not in got_keys
 
 
+class TestOneShard:
+    def test_index_with_writes_after_bulkload_vs_dict_oracle(self):
+        """An ``Aulid`` that took inserts and deletes after its bulkload,
+        wrapped as a one-shard partition, serves a mixed trace of gets,
+        upserts, deletes and scans exactly like a dict oracle — across the
+        compactions its gamma forces."""
+        keys = make_dataset("covid", 1_000, seed=4)
+        idx = Aulid(BlockDevice(), cfg=AulidConfig(**SMALL_GEOM))
+        idx.bulkload(keys, payloads_for(keys))
+        oracle = {int(k): int(p) for k, p in zip(keys, payloads_for(keys))}
+        rng = np.random.default_rng(17)
+        for k in rng.integers(int(keys[0]), int(keys[-1]), 120):
+            idx.insert(int(k), int(k) % 991)
+            oracle[int(k)] = int(k) % 991
+        for k in rng.choice(keys, 40, replace=False):
+            assert idx.delete(int(k))
+            oracle.pop(int(k))
+        assert idx.n_items == len(oracle)
+        eng = ShardedIndexEngine(one_shard(idx), gamma=0.03)
+        st = eng.stats()
+        assert st["num_shards"] == 1 and st["read_backend"] == "jnp"
+        for step in range(6):
+            srt = sorted(oracle)
+            checks = []
+            for _ in range(16):
+                k = (int(rng.choice(srt)) if rng.random() < 0.7
+                     else int(rng.integers(0, 2**50)))
+                checks.append((eng.get(k), k))
+            for i in range(10):
+                k = (int(rng.integers(0, 2**50)) if rng.random() < 0.6
+                     else int(rng.choice(srt)))
+                eng.insert(k, 1000 * step + i)
+                oracle[k] = 1000 * step + i
+            for _ in range(4):
+                k = (int(rng.choice(srt)) if rng.random() < 0.75
+                     else int(rng.integers(0, 2**50)))
+                r = eng.delete(k)
+                checks.append((r, oracle.pop(k, None) is not None))
+            for _ in range(3):
+                checks.append((eng.scan(int(rng.choice(srt)), 12), None))
+            eng.step()
+            if step % 2:
+                eng.drain_compactions()
+            srt = sorted(oracle)
+            for r, want in checks:
+                assert r.done
+                if r.op == "get":
+                    assert r.result == oracle.get(want), (step, r.key)
+                elif r.op == "delete":
+                    assert r.result is want, (step, r.key)
+                else:
+                    j = bisect.bisect_left(srt, r.key)
+                    assert r.result == [(k, oracle[k])
+                                        for k in srt[j:j + 12]], step
+        eng.drain_compactions()
+        st = eng.stats()
+        assert st["num_shards"] == 1 and st["compactions"] >= 1
+        assert eng.shards[0].idx is idx
+        assert dict(idx.scan(0, idx.n_items)) == oracle
+        idx.check_invariants()
+
+
 class TestShardLocalCompaction:
     def test_cold_shards_keep_snapshot_epoch(self):
         """Writes confined to one shard's range compact that shard only;
         cold shards' mirrors keep their snapshot epoch (the structural
         property the p99 benchmark gate rests on)."""
-        keys, mono, shrd = mk_engines(num_shards=4, gamma=0.01)
+        keys, one, shrd = mk_engines(num_shards=4, gamma=0.01)
         hot = 1
         lo = int(shrd.part.bounds[0]) + 1
         hi = int(shrd.part.bounds[1])
@@ -138,7 +213,7 @@ class TestScanBucketing:
         keys = make_dataset("covid", 800, seed=1)
         idx = Aulid(BlockDevice(), cfg=AulidConfig(**SMALL_GEOM))
         idx.bulkload(keys, payloads_for(keys))
-        eng = IndexEngine(idx, gamma=10.0)
+        eng = ShardedIndexEngine(one_shard(idx), gamma=10.0)
         reqs = [eng.scan(int(keys[40]), c) for c in (3, 5, 7, 8, 12, 16)]
         eng.step()
         for r, c in zip(reqs, (3, 5, 7, 8, 12, 16)):
@@ -175,12 +250,12 @@ def _mixed_requests(eng, keys) -> None:
 
 
 class TestPhaseAccounting:
-    @pytest.mark.parametrize("which", ["mono", "sharded"])
+    @pytest.mark.parametrize("which", ["one_shard", "three_shards"])
     def test_step_phases_add_up_within_the_step(self, which):
         """Every phase that ran in a step with writes, gets and scans counts
         above 0, and the request-path phases fit inside the step's own."""
-        keys, mono, shrd = mk_engines(gamma=10.0)       # no compaction
-        eng = mono if which == "mono" else shrd
+        keys, one, shrd = mk_engines(gamma=10.0)       # no compaction
+        eng = one if which == "one_shard" else shrd
         _mixed_requests(eng, keys)
         d = _phase_delta(eng, eng.step)
         for p in ("write_apply", "write_host", "read_dispatch", "read_wait",
@@ -192,12 +267,12 @@ class TestPhaseAccounting:
         for gone in ("throughput_ops_s", "p99_step_s", "mean_read_batch"):
             assert gone not in eng.stats()
 
-    @pytest.mark.parametrize("which", ["mono", "sharded"])
+    @pytest.mark.parametrize("which", ["one_shard", "three_shards"])
     def test_background_compaction_times_build_and_install(self, which):
         """A forced background compaction raises ``compact_build_s`` (timed
         on the pool thread, added at install) and ``install_s``."""
-        keys, mono, shrd = mk_engines(gamma=0.02, async_compact=True)
-        eng = mono if which == "mono" else shrd
+        keys, one, shrd = mk_engines(gamma=0.02, async_compact=True)
+        eng = one if which == "one_shard" else shrd
         rng = np.random.default_rng(5)
         for k in rng.integers(int(keys[0]), int(keys[-1]), 200):
             eng.insert(int(k), 1)
@@ -209,7 +284,7 @@ class TestPhaseAccounting:
         assert d["compact_build"] > 0 and d["install"] > 0
         assert eng.steps == steps
 
-    @pytest.mark.parametrize("which", ["mono", "sharded"])
+    @pytest.mark.parametrize("which", ["one_shard", "three_shards"])
     def test_phases_are_profiler_spans_inside_the_step(self, tmp_path,
                                                        which):
         """With a profiler trace running, each phase is an ``aulid.*`` span
@@ -217,8 +292,8 @@ class TestPhaseAccounting:
         ``step()``: one span per phase entered, not one per request."""
         import jax
         from jax.profiler import ProfileData
-        keys, mono, shrd = mk_engines(gamma=10.0)
-        eng = mono if which == "mono" else shrd
+        keys, one, shrd = mk_engines(gamma=10.0)
+        eng = one if which == "one_shard" else shrd
         _mixed_requests(eng, keys)
         eng.step()                          # compile outside the trace
         _mixed_requests(eng, keys)
