@@ -239,42 +239,50 @@ def merge_overlay_pack_jnp(pack: jnp.ndarray, batch: jnp.ndarray,
     of the host oracle, so the merged pack is bit-identical to a full host
     repack at the same capacity.  The caller guarantees ``cap_out`` covers
     the merged live count (it knows both host-side fill counts exactly);
-    output positions are computed by rank arithmetic, so no sort runs on
-    device: O(Ca + Cb) scatter work per merge.
+    output positions are computed by rank arithmetic from the batch side,
+    so no sort runs on device and no search runs per overlay slot: one
+    search of the batch into the overlay, then O(Ca + Cb) scatter work.
+    Overlay slot ``i`` lands at ``i`` plus the number of *new* batch keys
+    below it, counted by one fused compare-and-sum over the batch; a slot
+    the batch overwrites lands where the overwriting entry does, and the
+    batch is scattered last, so it wins without a mask.
     """
     ak, ap, at = pack[0], pack[1], pack[2]
     bk, bp, bt = batch[0], batch[1], batch[2]
     ca = ak.shape[0]
-    cb = bk.shape[0]
     um = jnp.uint64(UINT64_MAX)
     live_a = ak != um
     live_b = bk != um
-    # overlay keys overwritten by the batch (padding resolves to live_a=False)
-    posb = jnp.searchsorted(bk, ak, side="left").astype(jnp.int32)
-    in_b = (posb < cb) & (jnp.take(bk, jnp.clip(posb, 0, cb - 1)) == ak)
-    surv_a = live_a & ~in_b
-    # batch keys that overwrite an overlay key, and dead[j] == how many of
-    # them lie among the first j batch entries (prefix sum over the batch)
+    # insertion point of each batch key among the overlay's keys, and the
+    # batch keys that overwrite an overlay key
     posa = jnp.searchsorted(ak, bk, side="left").astype(jnp.int32)
     in_a = (posa < ca) & (jnp.take(ak, jnp.clip(posa, 0, ca - 1)) == bk)
-    common_bi = (live_b & in_a).astype(jnp.int32)
-    dead = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(common_bi)])
-    # rank of each surviving overlay key among survivors: live keys are a
-    # sorted prefix, so the overwritten keys before slot i are the common
-    # keys below ak[i], i.e. dead[posb[i]] (posb == count of live batch
-    # keys strictly below ak[i]; padding keys == UINT64_MAX sort above
-    # every live key).  No prefix sum over the overlay: its cost and the
-    # compile time of one grow with the pack, the batch's stay small.
-    pos_a = jnp.arange(ca, dtype=jnp.int32) - jnp.take(dead, posb) + posb
+    common = live_b & in_a
+    # a new batch key bk[j] lies below overlay slot i iff posa[j] <= i
+    # (live keys are a sorted prefix), so slot i lands at i + shift[i].
+    # Counted as one compare-and-sum over the batch axis: a binary search
+    # per slot, or a prefix sum over the overlay, costs work or compile
+    # time that grows with the pack.
+    newpos = jnp.where(live_b & ~in_a, posa, ca)
+    slot = jnp.arange(ca, dtype=jnp.int32)
+    shift = jnp.sum(newpos[:, None] <= slot[None, :], axis=0,
+                    dtype=jnp.int32)
+    pos_a = slot + shift
+    # dead[j] == how many overwriting batch keys lie among the first j
+    # batch entries (prefix sum over the batch)
+    dead = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                            jnp.cumsum(common.astype(jnp.int32))])
     # rank of each live batch key among batch entries
     live_bi = live_b.astype(jnp.int32)
     rank_b = jnp.cumsum(live_bi) - live_bi
     # surviving overlay keys strictly below bk[j]: all overlay keys below it
     # minus the overwritten ones below it (= batch∩overlay keys before j)
     pos_b = rank_b + posa - dead[:-1]
-    # disjoint scatter: survivors and batch entries interleave into one
-    # sorted run; dropped slots scatter to the (out-of-range) sentinel
-    idx_a = jnp.where(surv_a, pos_a, cap_out)
+    # survivors and batch entries interleave into one sorted run; an
+    # overwritten overlay slot and its batch entry share a position, and
+    # the batch's scatter runs second; padding scatters to the
+    # (out-of-range) sentinel
+    idx_a = jnp.where(live_a, pos_a, cap_out)
     idx_b = jnp.where(live_b, pos_b, cap_out)
     out_k = jnp.full((cap_out,), um, dtype=jnp.uint64)
     out_p = jnp.zeros((cap_out,), dtype=jnp.uint64)

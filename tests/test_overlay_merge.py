@@ -65,6 +65,46 @@ def rand_dict(rng, n, overlap_keys=()):
     return d
 
 
+_EVENS = {2 * i + 100: (i, False) for i in range(CAP_B)}
+_TOP = 2**64 - 2                      # the largest key below the padding
+# (overlay dict, batch dict, cap_out): cases of the survivors' rank, each
+# with distinct payloads so a misplaced entry shows
+RANK_CASES = {
+    "all_new": (_EVENS, {2 * i + 101: (500 + i, i % 2 == 0)
+                         for i in range(CAP_B)}, CAP_OUT),
+    "all_overwrite": (_EVENS, {k: (900 + p, p % 3 == 0)
+                               for k, (p, _) in _EVENS.items()}, CAP_OUT),
+    "batch_below": (_EVENS, {i: (700 + i, False) for i in range(CAP_B)},
+                    CAP_OUT),
+    "batch_above": (_EVENS, {10_000 + i: (700 + i, i == 3)
+                             for i in range(CAP_B)}, CAP_OUT),
+    # new key, overwrite, new key, overwrite ... slot by slot
+    "alternating": (_EVENS, {k: (800 + k, k % 8 == 3)
+                             for i in range(CAP_B // 2)
+                             for k in (4 * i + 99, 4 * i + 100)}, CAP_OUT),
+    # the window's every slot is live; one new key lands past the last
+    "full_window_new_last": ({3 * i + 1: (i, i % 5 == 0)
+                              for i in range(CAP_A)},
+                             {0: (40, False), 50: (41, True),
+                              3 * CAP_A + 7: (42, False)}, CAP_OUT),
+    "extreme_keys_in_batch": ({2**40 + i: (i, False) for i in range(8)},
+                              {0: (1, False), _TOP: (2, False),
+                               2**40 + 3: (3, True)}, CAP_OUT),
+    "extreme_keys_in_overlay": ({0: (1, False), 7: (2, True),
+                                 _TOP: (3, False)},
+                                {0: (4, True), 5: (5, False),
+                                 _TOP - 1: (6, False), _TOP: (7, True)},
+                                CAP_OUT),
+    "tombstones_over_tombstones": ({k: (0, True) for k in _EVENS},
+                                   {k: (0, True) for k in
+                                    list(_EVENS)[::2] + [99, 1_000]},
+                                   CAP_OUT),
+    # capacity growth: cap_out past the two inputs' slots together
+    "growth": (_EVENS, {2 * i + 101: (600 + i, i % 4 == 0)
+                        for i in range(CAP_B)}, 2 * CAP_OUT),
+}
+
+
 def assert_all_merge_paths(a: dict, b: dict, cap_out=CAP_OUT,
                            cap_a=CAP_A, cap_b=CAP_B):
     """jnp merge, Pallas kernel (interpret), and vmapped reference all
@@ -105,6 +145,14 @@ class TestMergeParity:
         a = {k: (k + 1, False) for k in range(10, 26)}
         b = {k: (k + 500, k % 3 == 0) for k in range(10, 26)}
         assert_all_merge_paths(a, b)
+
+    @pytest.mark.parametrize("case", sorted(RANK_CASES))
+    def test_rank_arithmetic_cases(self, case):
+        """Survivors land at their slot plus the new batch keys below it:
+        the placements where that count is all, none, or alternates, and
+        the ends of the key space, of the window and of the capacity."""
+        a, b, cap_out = RANK_CASES[case]
+        assert_all_merge_paths(a, b, cap_out=cap_out)
 
     def test_cap_growth_and_identity_cap(self):
         a = {k: (k, False) for k in range(0, 60, 2)}

@@ -17,6 +17,7 @@ one process at a time may load the TPU library, and the test runner's
 workers all import this file.
 """
 import dataclasses
+import re
 
 import pytest
 
@@ -207,11 +208,14 @@ def test_merge_kernel(one_chip, S, ca, cb):
 @pytest.mark.parametrize("w", [16384, 1 << 20])
 def test_merge_window_200m(one_chip, w):
     """The jnp delta merge over a window of the 200M-key deployment's pack,
-    and its write-back into the whole (3, 2^24) pack."""
+    and its write-back into the whole (3, 2^24) pack.  The merge holds one
+    loop, the search of the batch into the window: no binary search runs
+    per window slot."""
     pack = _sds((3, OVERLAY_CAP_200M), jnp.uint64, one_chip)
     head = _sds((3, w), jnp.uint64, one_chip)
     batch = _sds((3, 8192), jnp.uint64, one_chip)
     merged = merge_overlay_pack_jnp.lower(head, batch, cap_out=w).compile()
     assert merged.out_info.shape == (3, w)
+    assert len(re.findall(r" while\(", merged.as_text())) <= 1
     back = overlay_merge_write_back.lower(pack, head).compile()
     assert back.out_info.shape == (3, OVERLAY_CAP_200M)
